@@ -33,6 +33,7 @@ import sys
 import traceback
 
 from benchmarks.common import write_json
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -45,6 +46,7 @@ def main() -> None:
         "(us_per_call + parsed derived metrics + git SHA)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (
         analysis_throughput,

@@ -56,6 +56,7 @@ from benchmarks.beyond_paper_threepool import (
 )
 from benchmarks.common import emit, write_json
 from repro.core.pools import PoolConfig, n_seq_for_cmax
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import TelemetryConfig
 from repro.sim import A100_LLAMA3_70B, plan_fleet, run_fleet, run_fleet_grid
 from repro.traces import TraceSpec, generate_trace_columns
@@ -436,6 +437,7 @@ def main() -> None:
         help="write the emitted rows as a JSON artifact (see benchmarks.common)",
     )
     args = parser.parse_args()
+    enable_compile_cache()
     for n in args.requests:
         if args.backends:
             backends = tuple(args.backends.split(","))

@@ -227,7 +227,7 @@ class DtypeDisciplineRule(Rule):
                 ),
                 hint=(
                     "event times are float64 accumulations; run compiled "
-                    "entries under jax.experimental.enable_x64"
+                    "entries under jax.enable_x64()"
                 ),
             ),
         )

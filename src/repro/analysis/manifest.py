@@ -102,8 +102,8 @@ DEFAULT_MANIFEST: dict = {
     # ------------------------------------------------------------------
     # dtype-discipline: float64 op-order contract for DES time math.
     # Scoped to the compiled engine plus the one device kernel that IS
-    # event-time math (repro/kernels/sim_decode.py — its jnp twin and
-    # Pallas body must accumulate bit-identical float64 event times).
+    # event-time math (repro/kernels/sim_decode.py — its decode-advance
+    # pass must accumulate the engines' float64 event times bit for bit).
     # Other device kernels pick compute precision explicitly per
     # accelerator (f32/bf16 accumulators) and stay outside the contract.
     # ------------------------------------------------------------------
@@ -137,7 +137,7 @@ DEFAULT_MANIFEST: dict = {
             "repro/sim/jax_engine.py": ["_runner", "_aot"],
         },
         "kernels_note": "repro/kernels/* excluded except sim_decode.py: "
-        "pallas compute kernels (attention, scan) choose their own "
+        "the Pallas compute kernels (attention, scan) choose their own "
         "compute precision, but sim_decode advances DES event times and "
         "must hold the same float64 op-order contract as the engines; "
         "event-time constants flow through timing.constants_f64()",

@@ -22,24 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax moved shard_map out of jax.experimental (and renamed check_rep →
-# check_vma) across releases; accept both spellings.
-if hasattr(jax, "shard_map"):
 
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-
-else:  # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -1,33 +1,22 @@
 """Pallas TPU kernels for the serving hot spots, with jnp oracles.
 
-* ``flash_attention``      — prefill causal attention (GQA via index-map
+* ``flash_attention``    — prefill causal attention (GQA via index-map
   folding)
-* ``paged_attention``      — decode over block-table KV pages (vLLM→TPU
+* ``paged_attention``    — decode over block-table KV pages (vLLM→TPU
   port)
-* ``ssd_scan``             — Mamba-2 chunked state-space scan
-* ``decode_advance_pallas`` — the jax DES backend's fused decode-advance
-  round (one program per instance row), with ``decode_advance_jnp`` as
-  its bit-identical jnp twin/oracle
+* ``ssd_scan``           — Mamba-2 chunked state-space scan
+* ``decode_advance_jnp`` — the jax DES backend's fused decode-advance
+  round (plain jnp, float64 event times)
 
-Validated with ``interpret=True`` on CPU against :mod:`repro.kernels.ref`
-(attention/scan, numeric tolerance) and the jnp twin (sim_decode,
-bit-identity); compiled by Mosaic on real TPU backends. Off-TPU the
-kernels default to interpreter mode so CPU CI still executes the kernel
-bodies — ``sim_decode`` additionally keeps the jnp twin as the engine's
-default off-TPU path because its float64 event-time contract has no
-native TPU execution yet (``REPRO_SIM_PALLAS=1`` forces the kernel).
+The Pallas kernels are validated with ``interpret=True`` on CPU against
+:mod:`repro.kernels.ref` (numeric tolerance) and compiled by Mosaic on
+TPU. Off-TPU they default to interpreter mode so CPU CI still executes
+the kernel bodies. None of them is on the serving path today: ``models/``
+uses the jnp attention in :mod:`repro.models.layers`.
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; alias the
-# old spelling once here (package __init__ runs before any kernel submodule)
-# so every kernel can use the new name unconditionally.
-if not hasattr(_pltpu, "CompilerParams"):  # pragma: no cover - version shim
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-
 from repro.kernels.ops import flash_attention, paged_attention, ssd_scan
-from repro.kernels.sim_decode import decode_advance_jnp, decode_advance_pallas
+from repro.kernels.sim_decode import decode_advance_jnp
 from repro.kernels import ref
 
 __all__ = [
@@ -35,6 +24,5 @@ __all__ = [
     "paged_attention",
     "ssd_scan",
     "decode_advance_jnp",
-    "decode_advance_pallas",
     "ref",
 ]

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# repro.kernels.__init__ (always initialized first) aliases the old
-# pltpu.TPUCompilerParams spelling to CompilerParams on legacy jax.
-
 NEG_INF = -1e30
 
 

@@ -6,48 +6,26 @@ spends most of its time in a dense per-instance pass over the
 and feed it one chunk, compute the event-distance k-jump (completion /
 truncation / time-limit, with the KV-growth over-check), advance decode
 state, and stage the completion/truncation records for the scatter that
-follows. This module implements that pass twice, with identical op
-order:
+follows.
 
-* :func:`decode_advance_jnp` — the reference implementation, pure
-  ``jnp`` over the full ``(I, S)`` arrays. This is the oracle and the
-  default path on CPU/GPU hosts; it is bit-identical to the NumPy
-  engine's ``VectorPoolSim._round`` by construction (same formulas,
-  same IEEE-754 op order, float64 event times).
-* :func:`decode_advance_pallas` — a Pallas kernel, grid ``(I,)`` with
-  one program per instance row, each block a ``(1, S)`` slot row in
-  VMEM. On non-TPU backends it runs in **interpreter mode**
-  (``interpret=True``, the :mod:`repro.kernels` convention) so CPU CI
-  exercises the kernel body; on TPU it compiles via Mosaic. Note the
-  event-time contract is float64, which TPUs do not execute natively —
-  the compiled-TPU path is a forward-looking port target, and the
-  engine selects the jnp twin by default off-TPU
-  (``REPRO_SIM_PALLAS=1`` forces the kernel, used by the parity tests).
-
-Both paths return the same dict of advanced arrays and staging masks;
-``tests/test_kernels.py`` asserts they are bit-identical in interpreter
-mode and ``tests/test_vector_engine.py`` runs a whole fleet through the
-forced-Pallas engine against the scalar reference engine.
+:func:`decode_advance_jnp` is that pass in plain ``jnp`` over the full
+``(I, S)`` arrays, the one implementation on every backend. It is
+bit-identical to the NumPy engine's ``VectorPoolSim._round`` by
+construction (same formulas, same IEEE-754 op order, float64 event
+times). XLA emulates float64 on TPU in plain jnp code; a Pallas kernel
+cannot take float64 operands there, so a kernel form of this pass needs
+an integer event clock first.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pools import KV_BLOCK_TOKENS
 
 #: Sentinels for "no constraint" in masked min-reductions (int32-safe).
 _BIG_I = 1 << 30
 _BIG_F = 1.0e18
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _blocks_for(tok):
@@ -75,7 +53,7 @@ def decode_advance_jnp(
     chunk: int,
     c_max: int,
 ):
-    """One fused decode-advance over the full slot arrays (the oracle).
+    """One fused decode-advance over the full slot arrays.
 
     Identical formulas and op order to ``VectorPoolSim._round``'s
     k-jump/advance section; every float op is float64. Returns a dict:
@@ -146,216 +124,3 @@ def decode_advance_jnp(
         "comp": comp,
     }
 
-
-def _decode_kernel(
-    tlim_ref,  # (1, 1) f64
-    busy_ref,  # (1, 1) bool
-    now_ref,  # (1, 1) f64
-    nact_ref,  # (1, 1) i32
-    free_ref,  # (1, 1) i32
-    occ_ref,  # (1, S) bool
-    pre_ref,  # (1, S) i32
-    sq_ref,  # (1, S) i32
-    inp_ref,  # (1, S) i32
-    gen_ref,  # (1, S) i32
-    rem_ref,  # (1, S) i32
-    blk_ref,  # (1, S) i32
-    ft_ref,  # (1, S) f64
-    tr_ref,  # (1, S) bool
-    pre_out,  # (1, S) i32
-    dec_out,  # (1, S) bool
-    k_out,  # (1, 1) i32
-    end_out,  # (1, 1) f64
-    gen_out,  # (1, S) i32
-    rem_out,  # (1, S) i32
-    ft_out,  # (1, S) f64
-    trn_out,  # (1, S) bool
-    tra_out,  # (1, S) bool
-    comp_out,  # (1, S) bool
-    *,
-    w: float,
-    h: float,
-    chunk: int,
-    c_max: int,
-):
-    """Per-instance program: the same pass, one (1, S) slot row at a time."""
-    f64 = jnp.float64
-    i32 = jnp.int32
-    t_limit = tlim_ref[0, 0]
-    busy = busy_ref[0, 0]
-    now = now_ref[0, 0]
-    nact = nact_ref[0, 0]
-    free = free_ref[0, 0]
-    occ = occ_ref[...]
-    pre = pre_ref[...]
-    sq = sq_ref[...]
-    inp = inp_ref[...]
-    gen = gen_ref[...]
-    rem = rem_ref[...]
-    blk = blk_ref[...]
-    ft = ft_ref[...]
-    tr = tr_ref[...]
-
-    t_it = w + h * nact.astype(f64)
-    pmask = occ & (pre > 0)
-    has_pre = jnp.any(pmask) & busy
-    oldest = jnp.argmin(jnp.where(pmask, sq, _BIG_I))
-    take = jnp.minimum(pre[0, oldest], chunk)
-    pre_arr = pre.at[0, oldest].add(jnp.where(has_pre, -take, 0))
-
-    dec = occ & (pre_arr == 0) & (rem > 0)
-    ctx0 = inp + gen
-    k_complete = jnp.min(jnp.where(dec, rem, _BIG_I))
-    k_trunc = jnp.min(jnp.where(dec, c_max - ctx0, _BIG_I))
-    q = (t_limit - now) / t_it
-    k_time = jnp.where(jnp.isfinite(q), jnp.ceil(q - 1e-9), _BIG_F)
-    k = jnp.minimum(jnp.minimum(k_complete, k_trunc).astype(f64), k_time)
-    k = jnp.where(has_pre, 1.0, jnp.maximum(k, 1.0))
-    k = jnp.minimum(k, float(_BIG_I)).astype(i32)
-
-    ng = gen + jnp.where(dec, k, 0)
-    nd = jnp.where(occ, _blocks_for(inp + ng), 0)
-    over = busy & (jnp.maximum(nd - blk, 0).sum(dtype=i32) > free)
-    k = jnp.where(over, 1, k)
-    end = now + k.astype(f64) * t_it
-
-    kcol = jnp.where(dec, k, 0)
-    gen_a = gen + kcol
-    rem_a = rem - kcol
-    ft_a = jnp.where(dec & jnp.isnan(ft), now + t_it, ft)
-    trunc_n = dec & (inp + gen_a >= c_max) & (rem_a > 0) & busy
-    rem_a = jnp.where(trunc_n, 0, rem_a)
-    tr_a = tr | trunc_n
-    comp = dec & (rem_a == 0) & busy
-
-    pre_out[...] = pre_arr
-    dec_out[...] = dec
-    k_out[0, 0] = k
-    end_out[0, 0] = end
-    gen_out[...] = gen_a
-    rem_out[...] = rem_a
-    ft_out[...] = ft_a
-    trn_out[...] = trunc_n
-    tra_out[...] = tr_a
-    comp_out[...] = comp
-
-
-def decode_advance_pallas(
-    t_limit,
-    busy,
-    now,
-    nact,
-    free,
-    occ,
-    pre,
-    sq,
-    inp,
-    gen,
-    rem,
-    blk,
-    ft,
-    tr,
-    *,
-    w: float,
-    h: float,
-    chunk: int,
-    c_max: int,
-    interpret: bool | None = None,
-):
-    """Pallas twin of :func:`decode_advance_jnp` (same signature + dict).
-
-    Grid ``(I,)``; each program owns one instance's ``(1, S)`` slot row.
-    ``interpret`` defaults to True off-TPU so CPU CI runs the kernel
-    body through the Pallas interpreter.
-    """
-    if interpret is None:
-        interpret = _default_interpret()
-    I, S = occ.shape
-    f64 = jnp.float64
-    i32 = jnp.int32
-
-    col = lambda v, dt: jnp.asarray(v, dt).reshape(I, 1)  # noqa: E731
-    tlim2 = jnp.asarray(t_limit, f64).reshape(1, 1)
-    row_spec = pl.BlockSpec((1, S), lambda i: (i, 0))
-    col_spec = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    scl_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-
-    kernel = functools.partial(
-        _decode_kernel, w=w, h=h, chunk=chunk, c_max=c_max
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(I,),
-        in_specs=[
-            scl_spec,  # t_limit
-            col_spec,  # busy
-            col_spec,  # now
-            col_spec,  # nact
-            col_spec,  # free
-            row_spec,  # occ
-            row_spec,  # pre
-            row_spec,  # sq
-            row_spec,  # inp
-            row_spec,  # gen
-            row_spec,  # rem
-            row_spec,  # blk
-            row_spec,  # ft
-            row_spec,  # tr
-        ],
-        out_specs=[
-            row_spec,  # pre
-            row_spec,  # dec
-            col_spec,  # k
-            col_spec,  # end
-            row_spec,  # gen
-            row_spec,  # rem
-            row_spec,  # ft
-            row_spec,  # trunc_new
-            row_spec,  # tr
-            row_spec,  # comp
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((I, S), i32),
-            jax.ShapeDtypeStruct((I, S), jnp.bool_),
-            jax.ShapeDtypeStruct((I, 1), i32),
-            jax.ShapeDtypeStruct((I, 1), f64),
-            jax.ShapeDtypeStruct((I, S), i32),
-            jax.ShapeDtypeStruct((I, S), i32),
-            jax.ShapeDtypeStruct((I, S), f64),
-            jax.ShapeDtypeStruct((I, S), jnp.bool_),
-            jax.ShapeDtypeStruct((I, S), jnp.bool_),
-            jax.ShapeDtypeStruct((I, S), jnp.bool_),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
-    )(
-        tlim2,
-        col(busy, jnp.bool_),
-        col(now, f64),
-        col(nact, i32),
-        col(free, i32),
-        occ,
-        pre,
-        sq,
-        inp,
-        gen,
-        rem,
-        blk,
-        ft,
-        tr,
-    )
-    pre_a, dec, k, end, gen_a, rem_a, ft_a, trn, tra, comp = outs
-    return {
-        "pre": pre_a,
-        "dec": dec,
-        "k": k.reshape(I),
-        "end": end.reshape(I),
-        "gen": gen_a,
-        "rem": rem_a,
-        "ft": ft_a,
-        "trunc_new": trn,
-        "tr": tra,
-        "comp": comp,
-    }

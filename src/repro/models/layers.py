@@ -131,6 +131,18 @@ def flash_attention(
 
     q_chunk = min(q_chunk, lq)
     kv_chunk = min(kv_chunk, lk)
+    pad = -lq % q_chunk
+    if pad and causal and bias is None and lq == lk and q_chunk == kv_chunk:
+        # Right-pad a ragged causal sequence (a prompt bucket that is not a
+        # whole number of chunks) to whole chunks. Causal masking keeps the
+        # padded keys out of every real query; the padded rows are dropped.
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        out = flash_attention(
+            jnp.pad(q, widths), jnp.pad(k, widths), jnp.pad(v, widths),
+            causal=True, q_chunk=q_chunk, kv_chunk=kv_chunk,
+            causal_mode=causal_mode,
+        )
+        return out[:, :lq]
     if lq % q_chunk or lk % kv_chunk:
         raise ValueError(
             f"seq lengths ({lq},{lk}) must divide chunks ({q_chunk},{kv_chunk})"

@@ -47,22 +47,18 @@ from repro.models.params import ParamDef, stack_tree
 def attention_defs(cfg: ArchConfig, *, cross: bool = False) -> dict:
     h, k, dh, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_model
     prefix = "cross_" if cross else ""
+    # The default normal(0.02) init, not "scaled": that one reads the
+    # second-to-last axis as fan-in, a head axis here. With one KV head it
+    # drew w_k at std 1, a random model's attention scores ran into the
+    # hundreds, and softmax became an argmax that rounding noise flips.
     return {
         f"{prefix}attn_norm": ParamDef(
             (d,), ("embed",), init="zeros", dtype=jnp.float32
         ),
-        f"{prefix}w_q": ParamDef(
-            (d, h, dh), ("embed", "heads", "head_dim"), init="scaled"
-        ),
-        f"{prefix}w_k": ParamDef(
-            (d, k, dh), ("embed", "kv_heads", "head_dim"), init="scaled"
-        ),
-        f"{prefix}w_v": ParamDef(
-            (d, k, dh), ("embed", "kv_heads", "head_dim"), init="scaled"
-        ),
-        f"{prefix}w_o": ParamDef(
-            (h, dh, d), ("heads", "head_dim", "embed"), init="scaled"
-        ),
+        f"{prefix}w_q": ParamDef((d, h, dh), ("embed", "heads", "head_dim")),
+        f"{prefix}w_k": ParamDef((d, k, dh), ("embed", "kv_heads", "head_dim")),
+        f"{prefix}w_v": ParamDef((d, k, dh), ("embed", "kv_heads", "head_dim")),
+        f"{prefix}w_o": ParamDef((h, dh, d), ("heads", "head_dim", "embed")),
     }
 
 

@@ -27,6 +27,35 @@ from repro.serving.kv_cache import SlotAllocator, SlotKVCache, bucket_length
 from repro.serving.sampler import SamplingParams, sample
 
 
+def build_slot_decode(model: Model, batch_axes: Any):
+    """Jitted decode of one token in every slot.
+
+    ``vmap`` of the model's one-sequence ``decode_step`` over the slot
+    axis of each decode-state leaf (``batch_axes``, see
+    :func:`repro.serving.kv_cache.slot_batch_axes`), so every slot
+    writes its KV at its own position in one fused step. Signature:
+    ``(params, state, tokens (S,), index (S,)) -> (logits (S, V),
+    state)``; the state argument is donated."""
+
+    def single(params, state_slice, token, index):
+        state = jax.tree.map(
+            lambda x, ax: jnp.expand_dims(x, ax), state_slice, batch_axes
+        )
+        batch = {"tokens": token[None, None], "index": index}
+        logits, new_state = model.decode_step(params, state, batch)
+        new_state = jax.tree.map(
+            lambda x, ax: jnp.squeeze(x, ax), new_state, batch_axes
+        )
+        return logits[0], new_state
+
+    vm = jax.vmap(
+        single,
+        in_axes=(None, batch_axes, 0, 0),
+        out_axes=(0, batch_axes),
+    )
+    return jax.jit(vm, donate_argnums=(1,))
+
+
 @dataclasses.dataclass
 class ServeRequest:
     request_id: int
@@ -81,34 +110,9 @@ class ServingEngine:
         self.iterations = 0
 
         self._prefill = jax.jit(model.prefill)
-        self._decode = self._build_decode()
+        self._decode = build_slot_decode(model, self.cache.batch_axes)
         self._token_buf = np.zeros((n_slots,), np.int32)
         self._index_buf = np.zeros((n_slots,), np.int32)
-
-    # -- compiled decode over all slots ---------------------------------------
-    def _build_decode(self):
-        model = self.model
-        batch_axes = self.cache.batch_axes
-
-        def single(params, state_slice, token, index):
-            state = jax.tree.map(
-                lambda x, ax: jnp.expand_dims(x, ax),
-                state_slice,
-                batch_axes,
-            )
-            batch = {"tokens": token[None, None], "index": index}
-            logits, new_state = model.decode_step(params, state, batch)
-            new_state = jax.tree.map(
-                lambda x, ax: jnp.squeeze(x, ax), new_state, batch_axes
-            )
-            return logits[0], new_state
-
-        vm = jax.vmap(
-            single,
-            in_axes=(None, batch_axes, 0, 0),
-            out_axes=(0, batch_axes),
-        )
-        return jax.jit(vm, donate_argnums=(1,))
 
     # -- queue ------------------------------------------------------------------
     @property

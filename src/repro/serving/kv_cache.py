@@ -52,6 +52,26 @@ class SlotAllocator:
         return len(self.free)
 
 
+def slot_cell(c_max: int, n_slots: int) -> ShapeCell:
+    """The decode shape cell of one pool: ``n_slots`` x ``c_max`` tokens."""
+    return ShapeCell(
+        name="serving", kind="decode", seq_len=c_max, global_batch=n_slots
+    )
+
+
+def slot_batch_axes(model: Model, c_max: int, n_slots: int) -> Any:
+    """Per-leaf slot axis of the decode state (``None``: not batched).
+
+    The position of ``"serve_batch"`` in the model's logical cache axes;
+    computed from abstract shapes, so nothing is allocated."""
+    return jax.tree.map(
+        lambda ax: ax.index("serve_batch") if "serve_batch" in ax else None,
+        model.cache_axes(slot_cell(c_max, n_slots)),
+        is_leaf=lambda x: isinstance(x, tuple)
+        and all(isinstance(a, (str, type(None))) for a in x),
+    )
+
+
 class SlotKVCache:
     """Batched decode-state tree with slot-indexed insertion."""
 
@@ -59,21 +79,8 @@ class SlotKVCache:
         self.model = model
         self.c_max = c_max
         self.n_slots = n_slots
-        cell = ShapeCell(
-            name="serving", kind="decode", seq_len=c_max, global_batch=n_slots
-        )
-        self.cell = cell
-        self.state = model.init_cache(cell)
-        self.axes = model.cache_axes(cell)
-        # per-leaf batch axis = position of "serve_batch" in the logical axes
-        self.batch_axes = jax.tree.map(
-            lambda ax: ax.index("serve_batch") if "serve_batch" in ax else None,
-            self.axes,
-            is_leaf=lambda x: isinstance(x, tuple)
-            and all(isinstance(a, (str, type(None))) for a in x),
-        )
-        # per-leaf seq axis (KV caches only): position of the c_max dim
-        self.vmap_axes = self.batch_axes
+        self.state = model.init_cache(slot_cell(c_max, n_slots))
+        self.batch_axes = slot_batch_axes(model, c_max, n_slots)
 
     def insert_prefill(self, slot: int, prefill_state: Any) -> None:
         """Write a single-sequence prefill state (batch dim 1) into a slot."""

@@ -14,7 +14,8 @@ Three interchangeable fleet backends (``FleetSim(backend=...)``):
   (``tests/test_vector_engine.py``).
 * ``"jax"`` — fully compiled engine (:mod:`repro.sim.jax_engine`): the
   whole event loop as a jitted ``lax.while_loop`` over fixed-shape slot
-  arrays, bit-identical to the host backends in the exact classes.
+  arrays, bit-identical to the host backends in the exact classes on
+  the CPU (on a TPU, emulated float64 can move the last bits).
   Its batched sweep API :func:`run_fleet_grid` ``vmap``\\ s entire fleet
   simulations across threshold / instance-count / controller-gain axes —
   5×+ faster than the serial vectorized loop on ≥16-point sensitivity

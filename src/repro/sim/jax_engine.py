@@ -21,7 +21,7 @@ Identical to the host backends at ``coalesce_dt=0`` (per-arrival sync):
   are independent, so wave order equals the host's per-instance order);
 * event-distance k-jumps with the same integer/float formulas and the
   same IEEE-754 op order as ``VectorPoolSim._round`` (times are float64
-  — the entry points run under ``jax.experimental.enable_x64``);
+  — the entry points run under ``jax.enable_x64``);
 * the shared order-free batch preemption rule (advance → truncate →
   completion credit → evict the minimal youngest-first prefix of decoding
   survivors → allocate growth) as a *sort-free* victim-selection pass:
@@ -30,8 +30,9 @@ Identical to the host backends at ``coalesce_dt=0`` (per-arrival sync):
   (XLA:CPU sorts, batched gathers, and batched scatters all lower to
   ~40–50 µs serial loops inside a while body; the one-hot reduces fuse).
   The selected victims are identical, so routerless single-pool runs are
-  *bit-identical* to both host backends (asserted by
-  ``tests/test_vector_engine.py``).
+  *bit-identical* to both host backends on the CPU (asserted by
+  ``tests/test_vector_engine.py``). On a TPU, where XLA emulates
+  float64, event times can differ from the host's in their last bits.
 
 FIFO queues are request-indexed linked lists (``q_next[rid]`` + per
 instance head/tail); preempted sequences go to a bounded per-instance
@@ -80,10 +81,8 @@ The executables themselves are compiled ahead of time and cached
 ``enable_x64`` keyed by the static ``(spec, n, grid, g)`` shape, with
 wall-clock lower/compile times recorded in ``_COMPILE_STATS`` so the
 benchmark's ``jax_compile`` row measures compilation alone. The hot
-decode-advance pass is shared with :mod:`repro.kernels.sim_decode`,
-which provides a jnp twin (default on CPU/GPU hosts) and a Pallas kernel
-(default on TPU; force with ``REPRO_SIM_PALLAS=1``, interpreter mode off
-TPU) — both bit-identical, selected at trace time per ``_pallas_enabled``.
+decode-advance pass is :func:`repro.kernels.sim_decode.decode_advance_jnp`,
+the same pass on every backend.
 
 Routing, calibration, and control
 ---------------------------------
@@ -120,7 +119,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import time
 import warnings
 from typing import Optional, Sequence
@@ -129,7 +127,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.adaptive import (
     BoundaryMove,
@@ -146,7 +143,7 @@ from repro.core.calibration import (
 )
 from repro.core.pools import KV_BLOCK_TOKENS, PoolConfig, TOTAL_KV_BLOCKS
 from repro.core.router import jax_pool_ids
-from repro.kernels.sim_decode import decode_advance_jnp, decode_advance_pallas
+from repro.kernels.sim_decode import decode_advance_jnp
 from repro.sim.engine import _blocks_for
 from repro.sim.timing import TimingModel
 from repro.traces.generator import TraceColumns
@@ -174,27 +171,6 @@ _REC_DTYPES = (
 #: load-balance picks, wake seeding, submit-reject counter). Everything
 #: else is loop-invariant during a drain and stays out of its carry.
 _DRAIN_POOL_KEYS = ("qnext", "qh", "qt", "qlen", "load", "wake", "nrej")
-
-#: Test hook: force the Pallas decode path on (True) / off (False).
-_PALLAS_FORCE: Optional[bool] = None
-
-
-def _pallas_enabled() -> bool:
-    """Decode-advance path selection (part of the executable cache key).
-
-    Defaults to the Pallas kernel only on TPU (where it compiles via
-    Mosaic); hosts use the jnp twin — running the interpreter inside the
-    hot compiled loop would be pure overhead. ``REPRO_SIM_PALLAS=1``
-    forces the kernel (interpreter mode off-TPU; used by the parity
-    tests), ``=0`` forces it off.
-    """
-    if _PALLAS_FORCE is not None:
-        return bool(_PALLAS_FORCE)
-    env = os.environ.get("REPRO_SIM_PALLAS")
-    if env is not None:
-        return env.strip().lower() not in ("", "0", "false", "off")
-    return jax.default_backend() == "tpu"
-
 
 # ---------------------------------------------------------------------------
 # Static compile-time description
@@ -356,7 +332,6 @@ def _make_core(
     spec: _SimSpec,
     n: int,
     return_records: bool,
-    use_pallas: bool,
     gate: bool = True,
 ):
     """Build the single-lane simulation function for one (spec, n).
@@ -403,37 +378,15 @@ def _make_core(
     pg2 = jnp.arange(P)[:, None]
     ig2 = jnp.arange(I)[None, :]
 
-    if use_pallas:
-        # The Pallas kernel takes c_max as a static compile-time
-        # parameter, so the stacked decode runs one kernel call per
-        # pool and restacks (CI-parity path; the jnp twin below is the
-        # default off-TPU).
-        _advance_p = tuple(
-            functools.partial(
-                decode_advance_pallas, w=W, h=H, chunk=CHUNK, c_max=ps.c_max
-            )
-            for ps in spec.pools
-        )
+    _advance_1 = functools.partial(decode_advance_jnp, w=W, h=H, chunk=CHUNK)
 
-        def advance_all(t_limit, *args):
-            outs = [
-                _advance_p[p](t_limit, *(a[p] for a in args))
-                for p in range(P)
-            ]
-            return {k: jnp.stack([o[k] for o in outs]) for k in outs[0]}
-
-    else:
-        _advance_1 = functools.partial(
-            decode_advance_jnp, w=W, h=H, chunk=CHUNK
-        )
-
-        def advance_all(t_limit, *args):
-            # One vmapped twin over the pool axis; c_max rides along as
-            # a traced per-pool scalar (pure arithmetic in the twin).
-            return jax.vmap(
-                lambda cm, *a: _advance_1(t_limit, *a, c_max=cm),
-                in_axes=(0,) * (len(args) + 1),
-            )(cmax_v, *args)
+    def advance_all(t_limit, *args):
+        # One vmapped pass over the pool axis; c_max rides along as a
+        # traced per-pool scalar (pure arithmetic in the pass).
+        return jax.vmap(
+            lambda cm, *a: _advance_1(t_limit, *a, c_max=cm),
+            in_axes=(0,) * (len(args) + 1),
+        )(cmax_v, *args)
 
     def blocks_for(tok):
         return jnp.maximum(1, (tok + (KV_BLOCK_TOKENS - 1)) // KV_BLOCK_TOKENS)
@@ -442,7 +395,7 @@ def _make_core(
         return jnp.min(pools_["wake"])
 
     def core(trace, lane, rec0):
-        _count_trace(("sim_core", P, n, bool(return_records), bool(use_pallas)))
+        _count_trace(("sim_core", P, n, bool(return_records)))
         arr_t = trace["arr"]
         inp_t = trace["inp"]
         out_t = trace["outp"]
@@ -1158,13 +1111,12 @@ def _runner(
     n: int,
     return_records: bool,
     grid: bool,
-    use_pallas: bool = False,
 ):
     """Cached jitted simulation, specialized per (spec, n, outputs, vmap).
 
     The third argument (record buffers) is donated — XLA writes the
     scatters into the caller's buffers in place."""
-    core = _make_core(spec, n, return_records, use_pallas, gate=not grid)
+    core = _make_core(spec, n, return_records, gate=not grid)
     fn = jax.vmap(core, in_axes=(None, 0, 0)) if grid else core
     return jax.jit(fn, donate_argnums=(2,))
 
@@ -1173,7 +1125,7 @@ def _runner(
 # AOT executable cache + probes
 # ---------------------------------------------------------------------------
 
-#: {(spec, n, return_records, grid, g, pallas): {"lower_s", "compile_s"}}
+#: {(spec, n, return_records, grid, g): {"lower_s", "compile_s"}}
 _COMPILE_STATS: dict = {}
 
 #: Counters from the most recent compiled run (see :func:`last_run_stats`).
@@ -1220,7 +1172,6 @@ def _aot(
     return_records: bool,
     grid: bool,
     g: int,
-    use_pallas: bool,
 ):
     """AOT-compiled executable for one static shape key.
 
@@ -1228,7 +1179,7 @@ def _aot(
     lower/compile times land in ``_COMPILE_STATS`` so the benchmark's
     ``jax_compile`` row can report compilation alone (no run attached).
     """
-    with enable_x64(), warnings.catch_warnings():
+    with jax.enable_x64(), warnings.catch_warnings():
         if not return_records:
             # Without record outputs the donated buffers have no output
             # to alias into — donation still lets XLA recycle them as
@@ -1236,14 +1187,14 @@ def _aot(
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
-        fn = _runner(spec, n, return_records, grid, use_pallas)
+        fn = _runner(spec, n, return_records, grid)
         targs, lane, rec = _abstract_inputs(spec, n, grid, g)
         t0 = time.perf_counter()
         lowered = fn.lower(targs, lane, rec)
         t1 = time.perf_counter()
         compiled = lowered.compile()
         t2 = time.perf_counter()
-    _COMPILE_STATS[(spec, n, return_records, grid, g, use_pallas)] = {
+    _COMPILE_STATS[(spec, n, return_records, grid, g)] = {
         "lower_s": t1 - t0,
         "compile_s": t2 - t1,
     }
@@ -1264,7 +1215,7 @@ def compile_stats() -> list[dict]:
     """Every AOT compilation this process paid, with readable keys.
 
     One dict per ``_aot`` cache entry: ``n``, ``return_records``,
-    ``grid``, ``g``, ``pallas`` plus the measured ``lower_s`` /
+    ``grid``, ``g`` plus the measured ``lower_s`` /
     ``compile_s`` walls. Benchmarks use this to report grid-executable
     compile time without re-deriving the cache key."""
     return [
@@ -1273,7 +1224,6 @@ def compile_stats() -> list[dict]:
             "return_records": k[2],
             "grid": k[3],
             "g": k[4],
-            "pallas": k[5],
             **v,
         }
         for k, v in _COMPILE_STATS.items()
@@ -1305,7 +1255,7 @@ def _carry_report(spec: _SimSpec, n: int) -> dict:
             )
         )
 
-    with enable_x64():
+    with jax.enable_x64():
         pools = jax.eval_shape(lambda: _init_pools(spec, n))
         wins = jax.eval_shape(lambda: _init_windows(P, nb, win_cap))
     rec_bytes = sum(
@@ -1347,9 +1297,9 @@ def aot_compile(fleet, trace) -> dict:
     compilation."""
     cols = _as_columns(trace)
     spec, _, _ = _fleet_spec(fleet, cols)
-    key = (spec, len(cols), True, False, 0, _pallas_enabled())
+    key = (spec, len(cols), True, False, 0)
     cached = key in _COMPILE_STATS
-    with enable_x64():
+    with jax.enable_x64():
         _aot(*key)
     stats = dict(_COMPILE_STATS[key])
     stats["cached"] = cached
@@ -1576,8 +1526,8 @@ def run_fleet_jax(fleet, trace):
             telemetry=fleet.telemetry, slo=fleet.slo,
         )
 
-    with enable_x64():
-        exe = _aot(spec, n, True, False, 0, _pallas_enabled())
+    with jax.enable_x64():
+        exe = _aot(spec, n, True, False, 0)
         out = exe(_trace_arrays(cols, budgets), lane, _fresh_records(n))
         out = jax.tree_util.tree_map(np.asarray, out)
     _LAST_RUN.clear()
@@ -1872,8 +1822,8 @@ def run_fleet_grid(
         budgets, _ = precompute_budget_trajectory(cols, cal, epoch_cap=epoch_cap)
 
     lane = {"th": th_arr, "ninst": inst_arr, "ctrl": ctrl}
-    with enable_x64():
-        exe = _aot(spec, n, return_records, True, g, _pallas_enabled())
+    with jax.enable_x64():
+        exe = _aot(spec, n, return_records, True, g)
         out = exe(_trace_arrays(cols, budgets), lane, _fresh_records(n, g))
         out = jax.tree_util.tree_map(np.asarray, out)
     _LAST_RUN.clear()

@@ -195,9 +195,9 @@ class TestDtypeDiscipline:
 
     def test_x64_entry_inside_context_passes(self, tmp_path):
         code = (
-            "from jax.experimental import enable_x64\n"
+            "import jax\n"
             "def go(spec):\n"
-            "    with enable_x64():\n"
+            "    with jax.enable_x64():\n"
             "        return _runner(spec)\n"
         )
         assert _lint_one(tmp_path, JAX_ENGINE, code, DtypeDisciplineRule()) == []

@@ -207,30 +207,6 @@ def _decode_state(seed, I=3, S=8):
     )
 
 
-@pytest.mark.parametrize("seed", [0, 1, 7])
-def test_sim_decode_pallas_matches_jnp(seed):
-    from repro.kernels.sim_decode import (
-        decode_advance_jnp,
-        decode_advance_pallas,
-    )
-
-    s = _decode_state(seed)
-    kw = dict(w=2**-10, h=2**-13, chunk=512, c_max=2048)
-    with jax.experimental.enable_x64():
-        args = (
-            s["t_limit"], s["busy"], s["now"], s["nact"], s["free"],
-            s["occ"], s["pre"], s["sq"], s["inp"], s["gen"], s["rem"],
-            s["blk"], s["ft"], s["tr"],
-        )
-        out_j = decode_advance_jnp(*args, **kw)
-        out_p = decode_advance_pallas(*args, **kw)
-    assert set(out_j) == set(out_p)
-    for k in out_j:
-        a, b = np.asarray(out_j[k]), np.asarray(out_p[k])
-        assert a.dtype == b.dtype, k
-        assert np.array_equal(a, b, equal_nan=True), k
-
-
 def test_sim_decode_idle_instances_are_inert():
     """Idle (not busy) instances complete and truncate nothing — the
     busy-gated outputs the engine consumes unmasked must stay silent
@@ -240,7 +216,7 @@ def test_sim_decode_idle_instances_are_inert():
     s = _decode_state(3)
     s["busy"] = np.zeros_like(s["busy"])
     s["now"] = np.zeros_like(s["now"])
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         out = decode_advance_jnp(
             s["t_limit"], s["busy"], s["now"], s["nact"], s["free"],
             s["occ"], s["pre"], s["sq"], s["inp"], s["gen"], s["rem"],

@@ -53,6 +53,20 @@ def test_flash_attention_value_and_grad(mode, H, K):
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("mode", ["triangle", "masked"])
+def test_flash_attention_ragged_causal_length(mode):
+    """A causal length that is not a whole number of chunks is padded."""
+    rng = np.random.default_rng(2)
+    B, L, H, K, D = 1, 200, 4, 1, 32
+    q = jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, L, K, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, L, K, D)), jnp.float32)
+    out = flash_attention(q, k, v, q_chunk=64, kv_chunk=64, causal_mode=mode)
+    assert out.shape == (B, L, H, D)
+    expect = naive_attention(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=2e-5)
+
+
 def test_decode_attention_per_batch_lengths():
     rng = np.random.default_rng(1)
     B, S, H, K, D = 3, 64, 4, 2, 16

@@ -200,3 +200,24 @@ def test_full_config_param_counts():
 def test_moe_active_params():
     m = Model(get_config("llama4-maverick-400b-a17b"))
     assert m.active_param_count() < 0.1 * m.param_count()
+
+
+@pytest.mark.parametrize("arch", ["gemma-2b", "yi-6b"])
+def test_random_attention_scores_stay_soft(arch):
+    """At published width, random-init attention scores stay O(1).
+
+    Saturated scores make softmax an argmax, and then any rounding
+    difference between two correct code paths (prefill vs forward,
+    decode vs forward) flips the output; decode-vs-forward checks on
+    random weights lose all power."""
+    from repro.models.params import init_params
+    from repro.models.transformer import attention_defs
+
+    cfg = get_config(arch)
+    p = init_params(attention_defs(cfg), jax.random.key(0))
+    # unit-RMS rows, as the attention sees them after rms_norm
+    x = jax.random.normal(jax.random.key(1), (64, cfg.d_model), jnp.float32)
+    q = jnp.einsum("ld,dk->lk", x, p["w_q"][:, 0].astype(jnp.float32))
+    k = jnp.einsum("ld,dk->lk", x, p["w_k"][:, 0].astype(jnp.float32))
+    scores = q @ k.T / np.sqrt(cfg.head_dim)
+    assert float(jnp.std(scores)) < 4.0

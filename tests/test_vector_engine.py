@@ -1192,23 +1192,3 @@ class TestCoalescedJumpEquivalence:
         # the totals surface per-lane sums for benchmarking.
         assert stats["rounds"] == stats["iters"]
         assert stats["rounds_total"] <= stats["rounds"] * 2
-
-
-class TestPallasEngineParity:
-    """The Pallas decode-advance path (forced via ``_PALLAS_FORCE``)
-    must be bit-identical to the vmapped jnp twin through a full engine
-    run — same records, interpreter mode on CPU."""
-
-    def test_forced_pallas_matches_jnp_engine(self):
-        from repro.sim import jax_engine
-
-        cfg = PoolConfig("p", 2048, 8)
-        trace = poisson_trace(120, 150.0, 17, l_in=(16, 900), l_out=(1, 60))
-        sim_j, res_j = run_single_pool(trace, cfg, 2, "jax")
-        base = record_tuples(res_j, sim_j)
-        jax_engine._PALLAS_FORCE = True
-        try:
-            sim_p, res_p = run_single_pool(trace, cfg, 2, "jax")
-        finally:
-            jax_engine._PALLAS_FORCE = None
-        assert record_tuples(res_p, sim_p) == base
