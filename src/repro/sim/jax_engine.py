@@ -58,10 +58,11 @@ model (``benchmarks/sim_throughput.py`` tracks the byte totals as
   arrays, no other record columns.
 * **round sweep** — carries the slot arrays plus exactly the record
   columns that completion scatters write (``first``/``finish``/``out``/
-  ``pre``/``trunc``) and the admission-reject staging column ``rejt``.
-  Iteration count is surfaced as ``rounds`` (the pre-coalescing outer
-  loop ran one round per outer iteration, so ``rounds / iters`` is the
-  measured coalescing factor).
+  ``pre``/``trunc``) and the admission-reject staging column ``rejt``,
+  plus the loop counters (``ctr``: a few scalars). Iteration count is
+  surfaced as ``rounds`` (the pre-coalescing outer loop ran one round
+  per outer iteration, so ``rounds / iters`` is the measured coalescing
+  factor).
 
 Per-request record arrays live in **preallocated donated buffers**: the
 compiled entry takes a third argument ``rec0`` (see ``_fresh_records``)
@@ -83,6 +84,17 @@ wall-clock lower/compile times recorded in ``_COMPILE_STATS`` so the
 benchmark's ``jax_compile`` row measures compilation alone. The hot
 decode-advance pass is :func:`repro.kernels.sim_decode.decode_advance_jnp`,
 the same pass on every backend.
+
+Instrumentation
+---------------
+Always on. Inside the executable, each round phase runs under a
+``jax.named_scope`` (``_make_core``), and the carry counts admission
+waves, eviction passes, eviction need and live slot rows as the device
+executed them. On the host, each entry call is the span
+``repro.sim.run`` with children for routing precompute, compilation,
+staging, the device run and result assembly: ``TraceAnnotation`` events
+on a device profile's clock, whose seconds also land in
+:func:`last_run_stats` (one timing for both).
 
 Routing, calibration, and control
 ---------------------------------
@@ -117,8 +129,10 @@ on this backend (``FleetSim`` raises).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import itertools
 import time
 import warnings
 from typing import Optional, Sequence
@@ -285,6 +299,21 @@ def _init_windows(P: int, nb: int, win_cap: int) -> dict:
     }
 
 
+def _init_counters() -> dict:
+    """Loop counters carried through the rounds (see ``last_run_stats``).
+
+    ``live_slot_rounds`` is int64: live slots summed over rounds passes
+    2**31 on hour-long traces of a full fleet."""
+    z = jnp.asarray(0, jnp.int32)
+    return {
+        "rounds": z,
+        "adm_waves": z,
+        "evict_runs": z,
+        "evict_need": z,
+        "live_slot_rounds": jnp.asarray(0, jnp.int64),
+    }
+
+
 def _fresh_records(n: int, g: Optional[int] = None) -> dict:
     """Freshly-zeroed donated record buffers for one compiled call.
 
@@ -332,7 +361,7 @@ def _make_core(
     spec: _SimSpec,
     n: int,
     return_records: bool,
-    gate: bool = True,
+    lanes: Optional[str] = None,
 ):
     """Build the single-lane simulation function for one (spec, n).
 
@@ -347,15 +376,27 @@ def _make_core(
     op-dispatch-bound XLA:CPU backend P separately-traced bodies cost
     ~P× as much.
 
-    ``gate`` short-circuits the eviction pass with ``lax.cond`` when no
-    instance is over budget; the skipped branch is bit-identical to the
-    masked pass (``jsel = 0`` evicts nothing), so gating never changes
-    results — but under ``vmap`` a batched ``cond`` runs both branches
-    anyway, so ``_runner`` disables it for grid mode. ``gate`` also
-    selects the outer-loop shape: nested drain→sweep epochs for the
-    single-lane path, drain + exactly one round per outer iteration for
-    vmapped grids (a nested sweep loop would run to the max round count
-    over lanes per epoch — a measured 5.6× lockstep blowup at G=16).
+    ``lanes`` is the ``vmap`` axis name in grid mode and ``None`` for a
+    single lane. A single lane short-circuits the eviction pass with
+    ``lax.cond`` (the gate) when no instance is over budget; the skipped
+    branch is bit-identical to the masked pass (``jsel = 0`` evicts
+    nothing), so gating never changes results — but under ``vmap`` a
+    batched ``cond`` runs both branches anyway, so grids run the pass
+    ungated. The mode also selects the outer-loop shape: nested
+    drain→sweep epochs for the single-lane path, drain + exactly one
+    round per outer iteration for vmapped grids (a nested sweep loop
+    would run to the max round count over lanes per epoch — a measured
+    5.6× lockstep blowup at G=16).
+
+    Each round phase runs under a ``jax.named_scope`` (``dispatch`` with
+    ``window_step`` inside it, ``admit``, ``advance``,
+    ``record_scatter``, ``evict``, ``round_update``; ``fold`` after the
+    loop), so every HLO op carries its phase in its metadata and a
+    device profile's per-op events group by phase. The loop counters
+    (``ctr`` in the carry, returned in ``out``) count what the device
+    executed: under ``vmap`` a batched ``while`` runs until its last
+    lane is done, so the admission trip count and the eviction need are
+    ``lax.pmax``-ed over ``lanes`` (the identity for a single lane).
     """
     P = len(spec.pools)
     win = spec.win_size
@@ -366,6 +407,7 @@ def _make_core(
     W = np.float64(spec.w)
     H = np.float64(spec.h)
     CHUNK = spec.prefill_chunk
+    gate = lanes is None
     I = max(ps.max_inst for ps in spec.pools)
     S = max(ps.n_seq for ps in spec.pools)
     # Per-pool parameters as (P,) closure constants over the stacked
@@ -394,6 +436,11 @@ def _make_core(
     def wake_min_all(pools_):
         return jnp.min(pools_["wake"])
 
+    def executed(x):
+        # What the device ran: a batched ``while`` runs until its last
+        # lane is done, so under vmap that is the lane maximum.
+        return x if lanes is None else lax.pmax(x, lanes)
+
     def core(trace, lane, rec0):
         _count_trace(("sim_core", P, n, bool(return_records)))
         arr_t = trace["arr"]
@@ -406,6 +453,7 @@ def _make_core(
             return jnp.where(a < n, arr_t[jnp.minimum(a, n - 1)], jnp.inf)
 
         # ---- arrival drain (small carry: dispatch state only) -------------
+        @jax.named_scope("dispatch")
         def drain(c):
             # Loop-invariant pool state during a drain: dispatch touches
             # only the FIFO/load/wake/nrej fields, so the window snapshot's
@@ -420,6 +468,7 @@ def _make_core(
             }
 
             # ---- monitoring window + in-step AIMD controller --------------
+            @jax.named_scope("window_step")
             def window_step(sc, now_t):
                 fire = (sc["win_seen"] - sc["win_prev"]) >= win
                 cur = frozen["npre"] + sc["pools"]["nrej"] + frozen["ntr"]
@@ -596,7 +645,7 @@ def _make_core(
             }
 
         # ---- one masked round over the stacked pools ----------------------
-        def pool_round(st, rec, rejt, t_limit):
+        def pool_round(st, rec, rejt, ctr, t_limit):
             due = st["wake"] < t_limit
 
             # Admission fixpoint: one wave admits/rejects at most one head
@@ -618,12 +667,12 @@ def _make_core(
                 return stash, hrid, hc, hinp, hpc, need, rejm, admm
 
             def adm_cond(val):
-                st_, _ = val
+                st_, _, _ = val
                 *_, rejm, admm = adm_masks(st_)
                 return jnp.any(rejm | admm)
 
             def adm_body(val):
-                st_, rejt_ = val
+                st_, rejt_, waves_ = val
                 stash, hrid, hc, hinp, hpc, need, rejm, admm = adm_masks(st_)
                 prog = rejm | admm
                 # pop the head (victim stash first — head-of-line order)
@@ -711,242 +760,267 @@ def _make_core(
                         "nact": st_["nact"] + admm,
                     },
                     rejt_,
+                    waves_ + 1,
                 )
 
-            st, rejt = lax.while_loop(adm_cond, adm_body, (st, rejt))
-
-            nact = st["nact"]
-            busy = due & (nact > 0)
-            idle = due & ~busy
-            wake_idle = jnp.where(
-                idle,
-                jnp.where(st["qlen"] > 0, st["wake"] + 1e-9, jnp.inf),
-                st["wake"],
-            )
-            now = jnp.where(busy, st["wake"], 0.0)
-            bb = busy[:, :, None]
-            occ = st["occ"]
-            inp2, gen0, rem0, blk0 = st["inp"], st["gen"], st["rem"], st["blk"]
-
-            # fused decode-advance (repro.kernels.sim_decode): prefill
-            # chunk + event-distance k-jump + advance + completion staging,
-            # as the jnp twin (vmapped over the pool axis) or the Pallas
-            # kernel (one call per pool) — bit-identical paths.
-            adv = advance_all(
-                t_limit,
-                busy,
-                now,
-                nact,
-                st["free"],
-                occ,
-                st["pre"],
-                st["sq"],
-                inp2,
-                gen0,
-                rem0,
-                blk0,
-                st["ft"],
-                st["tr"],
-            )
-            pre_arr = adv["pre"]
-            dec = adv["dec"]
-            end = adv["end"]
-            gen_a = adv["gen"]
-            rem_a = adv["rem"]
-            ft_a = adv["ft"]
-            trunc_n = adv["trunc_new"]
-            tr_a = adv["tr"]
-            comp = adv["comp"]
-            ntr = st["ntr"] + jnp.sum(trunc_n, axis=(1, 2), dtype=i32)
-
-            # One scatter per pool per packed record buffer, over that
-            # pool's *real* ``(max_inst, n_seq)`` slot block. The
-            # stacked arrays are padded to ``(P, max I, max S)``, and
-            # XLA:CPU lowers a batched scatter to a serial per-row
-            # loop, so scattering the padded block pays for slots that
-            # can never complete (a ragged 4×128 + 12×16 topology pads
-            # 4.4×). Request ids are globally unique, so per-pool
-            # updates stay disjoint; non-completing slots hit the
-            # scratch row. Packing same-dtype columns keeps this at
-            # two scatter ops per pool per round instead of five.
-            ridx = jnp.where(comp, st["rid"], n)
-            recf_new = jnp.stack(
-                [ft_a, jnp.broadcast_to(end[:, :, None], (P, I, S))],
-                axis=-1,
-            )
-            reci_new = jnp.stack(
-                [gen_a, st["pc"], tr_a.astype(i32)], axis=-1
-            )
-            rf, ri = rec["recf"], rec["reci"]
-            for p in range(P):
-                ip, sp = spec.pools[p].max_inst, spec.pools[p].n_seq
-                idx_p = ridx[p, :ip, :sp]
-                rf = rf.at[idx_p].set(
-                    recf_new[p, :ip, :sp], mode="promise_in_bounds"
+            with jax.named_scope("admit"):
+                st, rejt, waves = lax.while_loop(
+                    adm_cond, adm_body, (st, rejt, jnp.asarray(0, i32))
                 )
-                ri = ri.at[idx_p].set(
-                    reci_new[p, :ip, :sp], mode="promise_in_bounds"
-                )
-            rec = {"recf": rf, "reci": ri}
-            free1 = st["free"] + jnp.sum(
-                jnp.where(comp, blk0, 0), axis=2, dtype=i32
-            )
-            ncomp = jnp.sum(comp, axis=2, dtype=i32)
 
-            surv = dec & (rem_a > 0) & bb
-            need_s = jnp.where(surv, blocks_for(inp2 + gen_a), blk0)
-            grow = jnp.where(surv, need_s - blk0, 0)
-            demand = grow.sum(axis=2, dtype=i32)
+            with jax.named_scope("advance"):
+                nact = st["nact"]
+                busy = due & (nact > 0)
+                idle = due & ~busy
+                wake_idle = jnp.where(
+                    idle,
+                    jnp.where(st["qlen"] > 0, st["wake"] + 1e-9, jnp.inf),
+                    st["wake"],
+                )
+                now = jnp.where(busy, st["wake"], 0.0)
+                bb = busy[:, :, None]
+                occ = st["occ"]
+                inp2, gen0 = st["inp"], st["gen"]
+                rem0, blk0 = st["rem"], st["blk"]
 
-            def evict_pass(_):
-                # Sort-free eviction scan. XLA:CPU sorts cost ~40 µs
-                # each inside a while body, so instead of
-                # lexsort/argsort the scan order (enq youngest-first,
-                # admission seq_no tie-break — a total order: seq_no is
-                # unique per instance) comes from pairwise-comparison
-                # ranks over the tiny (S, S) slot square, prefix sums
-                # from the same mask, and the victim stash from a
-                # rank-indexed scatter. Values are bit-identical to the
-                # sorted formulation (keys carry no NaNs and no -0/+0
-                # mix, so IEEE compare ≡ the sort's total order).
-                keyq = jnp.where(surv, -st["enq"], jnp.inf)
-                sq = st["sq"]
-                k_a, k_b = keyq[:, :, :, None], keyq[:, :, None, :]
-                sq_lt = sq[:, :, None, :] < sq[:, :, :, None]  # [a,b]: b<a
-                prec = (k_b < k_a) | ((k_b == k_a) & sq_lt)
-                rank = jnp.sum(prec, axis=3, dtype=i32)
-                le = prec | jnp.eye(S, dtype=bool)[None, None]
-                blkv = jnp.where(surv, blk0, 0)
-                cum_blk = jnp.sum(
-                    jnp.where(le, blkv[:, :, None, :], 0), axis=3, dtype=i32
+                # fused decode-advance (repro.kernels.sim_decode):
+                # prefill chunk + event-distance k-jump + advance +
+                # completion staging, as the jnp twin (vmapped over the
+                # pool axis) or the Pallas kernel (one call per pool) —
+                # bit-identical paths.
+                adv = advance_all(
+                    t_limit,
+                    busy,
+                    now,
+                    nact,
+                    st["free"],
+                    occ,
+                    st["pre"],
+                    st["sq"],
+                    inp2,
+                    gen0,
+                    rem0,
+                    blk0,
+                    st["ft"],
+                    st["tr"],
                 )
-                cum_grow = jnp.sum(
-                    jnp.where(le, grow[:, :, None, :], 0), axis=3, dtype=i32
-                )
-                okj = (
-                    demand[:, :, None] - cum_grow
-                    <= free1[:, :, None] + cum_blk
-                )
-                first_ok = jnp.min(jnp.where(okj, rank, S), axis=2)
-                jsel = jnp.where(
-                    demand <= free1,
-                    0,
-                    jnp.where(first_ok < S, first_ok + 1, 1),
-                )
-                ev = (rank < jsel[:, :, None]) & surv
-                nev = jnp.sum(ev, axis=2, dtype=i32)
+                pre_arr = adv["pre"]
+                dec = adv["dec"]
+                end = adv["end"]
+                gen_a = adv["gen"]
+                rem_a = adv["rem"]
+                ft_a = adv["ft"]
+                trunc_n = adv["trunc_new"]
+                tr_a = adv["tr"]
+                comp = adv["comp"]
+                ntr = st["ntr"] + jnp.sum(trunc_n, axis=(1, 2), dtype=i32)
 
-                # victims → stash, in admission (seq_no) order, ahead of
-                # the previous stash (requeue-at-head semantics). The
-                # permutation runs as one-hot select-reduces over the
-                # (S, S) square instead of gather/scatter: XLA:CPU's
-                # batched scatter and gather both cost ~50 µs inside a
-                # while body versus ~10 µs for the masked reduce, and
-                # the one-hot sums are exact (one source per slot).
-                vrank = jnp.sum(ev[:, :, None, :] & sq_lt, axis=3, dtype=i32)
-                rr = jnp.arange(S)
-                in_new = rr[None, None, :] < nev[:, :, None]
-                # vm[j, a]: stash slot j takes the victim in slot a
-                # (the one whose victim-rank is j); om[j, a]: slot j
-                # takes previous-stash slot a = j − n_victims.
-                vm = (
-                    ev[:, :, None, :]
-                    & (vrank[:, :, None, :] == rr[None, None, :, None])
-                    & in_new[:, :, :, None]
+            with jax.named_scope("record_scatter"):
+                # One scatter per pool per packed record buffer, over that
+                # pool's *real* ``(max_inst, n_seq)`` slot block. The
+                # stacked arrays are padded to ``(P, max I, max S)``, and
+                # XLA:CPU lowers a batched scatter to a serial per-row
+                # loop, so scattering the padded block pays for slots that
+                # can never complete (a ragged 4×128 + 12×16 topology pads
+                # 4.4×). Request ids are globally unique, so per-pool
+                # updates stay disjoint; non-completing slots hit the
+                # scratch row. Packing same-dtype columns keeps this at
+                # two scatter ops per pool per round instead of five.
+                ridx = jnp.where(comp, st["rid"], n)
+                recf_new = jnp.stack(
+                    [ft_a, jnp.broadcast_to(end[:, :, None], (P, I, S))],
+                    axis=-1,
                 )
-                om = (
-                    rr[None, None, None, :]
-                    == rr[None, None, :, None] - nev[:, :, None, None]
-                ) & ~in_new[:, :, :, None]
+                reci_new = jnp.stack(
+                    [gen_a, st["pc"], tr_a.astype(i32)], axis=-1
+                )
+                rf, ri = rec["recf"], rec["reci"]
+                for p in range(P):
+                    ip, sp = spec.pools[p].max_inst, spec.pools[p].n_seq
+                    idx_p = ridx[p, :ip, :sp]
+                    rf = rf.at[idx_p].set(
+                        recf_new[p, :ip, :sp], mode="promise_in_bounds"
+                    )
+                    ri = ri.at[idx_p].set(
+                        reci_new[p, :ip, :sp], mode="promise_in_bounds"
+                    )
+                rec = {"recf": rf, "reci": ri}
 
-                def stash(old3, vals):
-                    return jnp.sum(
-                        jnp.where(vm, vals[:, :, None, :], 0),
-                        axis=3,
-                        dtype=i32,
-                    ) + jnp.sum(
-                        jnp.where(om, old3[:, :, None, :], 0),
-                        axis=3,
-                        dtype=i32,
+            with jax.named_scope("evict"):
+                free1 = st["free"] + jnp.sum(
+                    jnp.where(comp, blk0, 0), axis=2, dtype=i32
+                )
+                ncomp = jnp.sum(comp, axis=2, dtype=i32)
+
+                surv = dec & (rem_a > 0) & bb
+                need_s = jnp.where(surv, blocks_for(inp2 + gen_a), blk0)
+                grow = jnp.where(surv, need_s - blk0, 0)
+                demand = grow.sum(axis=2, dtype=i32)
+
+                def evict_pass(_):
+                    # Sort-free eviction scan. XLA:CPU sorts cost ~40 µs
+                    # each inside a while body, so instead of
+                    # lexsort/argsort the scan order (enq youngest-first,
+                    # admission seq_no tie-break — a total order: seq_no is
+                    # unique per instance) comes from pairwise-comparison
+                    # ranks over the tiny (S, S) slot square, prefix sums
+                    # from the same mask, and the victim stash from a
+                    # rank-indexed scatter. Values are bit-identical to the
+                    # sorted formulation (keys carry no NaNs and no -0/+0
+                    # mix, so IEEE compare ≡ the sort's total order).
+                    keyq = jnp.where(surv, -st["enq"], jnp.inf)
+                    sq = st["sq"]
+                    k_a, k_b = keyq[:, :, :, None], keyq[:, :, None, :]
+                    sq_lt = sq[:, :, None, :] < sq[:, :, :, None]  # [a,b]: b<a
+                    prec = (k_b < k_a) | ((k_b == k_a) & sq_lt)
+                    rank = jnp.sum(prec, axis=3, dtype=i32)
+                    le = prec | jnp.eye(S, dtype=bool)[None, None]
+                    blkv = jnp.where(surv, blk0, 0)
+                    cum_blk = jnp.sum(
+                        jnp.where(le, blkv[:, :, None, :], 0), axis=3, dtype=i32
+                    )
+                    cum_grow = jnp.sum(
+                        jnp.where(le, grow[:, :, None, :], 0), axis=3, dtype=i32
+                    )
+                    okj = (
+                        demand[:, :, None] - cum_grow
+                        <= free1[:, :, None] + cum_blk
+                    )
+                    first_ok = jnp.min(jnp.where(okj, rank, S), axis=2)
+                    jsel = jnp.where(
+                        demand <= free1,
+                        0,
+                        jnp.where(first_ok < S, first_ok + 1, 1),
+                    )
+                    ev = (rank < jsel[:, :, None]) & surv
+                    nev = jnp.sum(ev, axis=2, dtype=i32)
+
+                    # victims → stash, in admission (seq_no) order, ahead of
+                    # the previous stash (requeue-at-head semantics). The
+                    # permutation runs as one-hot select-reduces over the
+                    # (S, S) square instead of gather/scatter: XLA:CPU's
+                    # batched scatter and gather both cost ~50 µs inside a
+                    # while body versus ~10 µs for the masked reduce, and
+                    # the one-hot sums are exact (one source per slot).
+                    vrank = jnp.sum(ev[:, :, None, :] & sq_lt, axis=3, dtype=i32)
+                    rr = jnp.arange(S)
+                    in_new = rr[None, None, :] < nev[:, :, None]
+                    # vm[j, a]: stash slot j takes the victim in slot a
+                    # (the one whose victim-rank is j); om[j, a]: slot j
+                    # takes previous-stash slot a = j − n_victims.
+                    vm = (
+                        ev[:, :, None, :]
+                        & (vrank[:, :, None, :] == rr[None, None, :, None])
+                        & in_new[:, :, :, None]
+                    )
+                    om = (
+                        rr[None, None, None, :]
+                        == rr[None, None, :, None] - nev[:, :, None, None]
+                    ) & ~in_new[:, :, :, None]
+
+                    def stash(old3, vals):
+                        return jnp.sum(
+                            jnp.where(vm, vals[:, :, None, :], 0),
+                            axis=3,
+                            dtype=i32,
+                        ) + jnp.sum(
+                            jnp.where(om, old3[:, :, None, :], 0),
+                            axis=3,
+                            dtype=i32,
+                        )
+
+                    vr = stash(st["vrid"], st["rid"])
+                    vi = stash(st["vinp"], inp2 + gen_a)
+                    vp = stash(st["vpc"], st["pc"] + 1)
+                    return ev, nev, vr, vi, vp
+
+                def no_evict(_):
+                    # demand ≤ free everywhere ⇒ jsel = 0 ⇒ nothing evicts
+                    # and the stash is untouched — same values, no sorts.
+                    return (
+                        jnp.zeros((P, I, S), bool),
+                        jnp.zeros((P, I), i32),
+                        st["vrid"],
+                        st["vinp"],
+                        st["vpc"],
                     )
 
-                vr = stash(st["vrid"], st["rid"])
-                vi = stash(st["vinp"], inp2 + gen_a)
-                vp = stash(st["vpc"], st["pc"] + 1)
-                return ev, nev, vr, vi, vp
-
-            def no_evict(_):
-                # demand ≤ free everywhere ⇒ jsel = 0 ⇒ nothing evicts
-                # and the stash is untouched — same values, no sorts.
-                return (
-                    jnp.zeros((P, I, S), bool),
-                    jnp.zeros((P, I), i32),
-                    st["vrid"],
-                    st["vinp"],
-                    st["vpc"],
+                need = jnp.any(demand > free1)
+                if gate:
+                    evict, nevict, vrid, vinp, vpc = lax.cond(
+                        need, evict_pass, no_evict, None
+                    )
+                else:
+                    evict, nevict, vrid, vinp, vpc = evict_pass(None)
+                npre = st["npre"] + jnp.sum(evict, axis=(1, 2), dtype=i32)
+                free1 = free1 + jnp.sum(
+                    jnp.where(evict, blk0, 0), axis=2, dtype=i32
                 )
 
-            if gate:
-                evict, nevict, vrid, vinp, vpc = lax.cond(
-                    jnp.any(demand > free1), evict_pass, no_evict, None
+            with jax.named_scope("round_update"):
+                keep = surv & ~evict
+                free1 = free1 - jnp.sum(
+                    jnp.where(keep, grow, 0), axis=2, dtype=i32
                 )
-            else:
-                evict, nevict, vrid, vinp, vpc = evict_pass(None)
-            npre = st["npre"] + jnp.sum(evict, axis=(1, 2), dtype=i32)
-            free1 = free1 + jnp.sum(
-                jnp.where(evict, blk0, 0), axis=2, dtype=i32
-            )
+                cleared = comp | evict
+                nact_a = nact - ncomp - nevict
+                qlen_a = st["qlen"] + nevict
+                alive_r = (nact_a > 0) | (qlen_a > 0)
 
-            keep = surv & ~evict
-            free1 = free1 - jnp.sum(
-                jnp.where(keep, grow, 0), axis=2, dtype=i32
-            )
-            cleared = comp | evict
-            nact_a = nact - ncomp - nevict
-            qlen_a = st["qlen"] + nevict
-            alive_r = (nact_a > 0) | (qlen_a > 0)
+                st = {
+                    **st,
+                    "occ": jnp.where(bb, occ & ~cleared, occ),
+                    "pre": pre_arr,
+                    "rem": jnp.where(bb, rem_a, rem0),
+                    "gen": jnp.where(bb, gen_a, gen0),
+                    "blk": jnp.where(
+                        bb, jnp.where(cleared, 0, jnp.where(keep, need_s, blk0)), blk0
+                    ),
+                    "ft": jnp.where(bb, ft_a, st["ft"]),
+                    "tr": jnp.where(bb, tr_a, st["tr"]),
+                    "vrid": jnp.where(bb, vrid, st["vrid"]),
+                    "vinp": jnp.where(bb, vinp, st["vinp"]),
+                    "vpc": jnp.where(bb, vpc, st["vpc"]),
+                    "vcnt": jnp.where(busy, st["vcnt"] + nevict, st["vcnt"]),
+                    "free": jnp.where(busy, free1, st["free"]),
+                    "nact": jnp.where(busy, nact_a, nact),
+                    "qlen": jnp.where(busy, qlen_a, st["qlen"]),
+                    "load": jnp.where(busy, st["load"] - ncomp, st["load"]),
+                    "wake": jnp.where(
+                        busy, jnp.where(alive_r, end, jnp.inf), wake_idle
+                    ),
+                    "npre": npre,
+                    "ntr": ntr,
+                }
 
-            st = {
-                **st,
-                "occ": jnp.where(bb, occ & ~cleared, occ),
-                "pre": pre_arr,
-                "rem": jnp.where(bb, rem_a, rem0),
-                "gen": jnp.where(bb, gen_a, gen0),
-                "blk": jnp.where(
-                    bb, jnp.where(cleared, 0, jnp.where(keep, need_s, blk0)), blk0
-                ),
-                "ft": jnp.where(bb, ft_a, st["ft"]),
-                "tr": jnp.where(bb, tr_a, st["tr"]),
-                "vrid": jnp.where(bb, vrid, st["vrid"]),
-                "vinp": jnp.where(bb, vinp, st["vinp"]),
-                "vpc": jnp.where(bb, vpc, st["vpc"]),
-                "vcnt": jnp.where(busy, st["vcnt"] + nevict, st["vcnt"]),
-                "free": jnp.where(busy, free1, st["free"]),
-                "nact": jnp.where(busy, nact_a, nact),
-                "qlen": jnp.where(busy, qlen_a, st["qlen"]),
-                "load": jnp.where(busy, st["load"] - ncomp, st["load"]),
-                "wake": jnp.where(
-                    busy, jnp.where(alive_r, end, jnp.inf), wake_idle
-                ),
-                "npre": npre,
-                "ntr": ntr,
-            }
-            return st, rec, rejt
+                # Loop counters: scalars beside the state update (the live
+                # slot sum is one (P, I) reduce of the post-admission nact).
+                ran = need.astype(i32) if gate else 1
+                ctr = {
+                    "rounds": ctr["rounds"] + 1,
+                    "adm_waves": ctr["adm_waves"] + executed(waves),
+                    "evict_runs": ctr["evict_runs"] + ran,
+                    "evict_need": ctr["evict_need"]
+                    + executed(need.astype(i32)),
+                    "live_slot_rounds": ctr["live_slot_rounds"]
+                    + jnp.sum(nact, dtype=jnp.int64),
+                }
+            return st, rec, rejt, ctr
 
         # ---- outer epoch loop: drain arrivals, then sweep rounds ----------
         def cond_fn(c):
             return (c["a"] < n) | jnp.isfinite(wake_min_all(c["pools"]))
 
         def one_round(c, t_limit):
-            pools_s, rec_s, rejt_s = pool_round(
-                c["pools"], c["rec"], c["rejt"], t_limit
+            pools_s, rec_s, rejt_s, ctr_s = pool_round(
+                c["pools"], c["rec"], c["rejt"], c["ctr"], t_limit
             )
             return {
                 **c,
                 "pools": pools_s,
                 "rec": rec_s,
                 "rejt": rejt_s,
-                "rounds": c["rounds"] + 1,
+                "ctr": ctr_s,
             }
 
         if gate:
@@ -957,37 +1031,37 @@ def _make_core(
                 # next arrival (t_limit is loop-invariant — `a` doesn't
                 # move during a sweep), instead of re-entering the outer
                 # body per round. The sweep carry is the slot state +
-                # the completion-written record columns only.
+                # the completion-written record columns + the counters.
                 t_limit = next_arr_at(c["a"])
 
                 def sweep_cond(s):
                     return wake_min_all(s[0]) < t_limit
 
                 def sweep_body(s):
-                    pools_s, rec_s, rejt_s, rounds = s
+                    pools_s, rec_s, rejt_s, ctr_s = s
                     cs = one_round(
                         {
                             **c,
                             "pools": pools_s,
                             "rec": rec_s,
                             "rejt": rejt_s,
-                            "rounds": rounds,
+                            "ctr": ctr_s,
                         },
                         t_limit,
                     )
-                    return (cs["pools"], cs["rec"], cs["rejt"], cs["rounds"])
+                    return (cs["pools"], cs["rec"], cs["rejt"], cs["ctr"])
 
-                pools_s, rec_s, rejt_s, rounds = lax.while_loop(
+                pools_s, rec_s, rejt_s, ctr_s = lax.while_loop(
                     sweep_cond,
                     sweep_body,
-                    (c["pools"], c["rec"], c["rejt"], c["rounds"]),
+                    (c["pools"], c["rec"], c["rejt"], c["ctr"]),
                 )
                 return {
                     **c,
                     "pools": pools_s,
                     "rec": rec_s,
                     "rejt": rejt_s,
-                    "rounds": rounds,
+                    "ctr": ctr_s,
                     "iters": c["iters"] + 1,
                 }
 
@@ -1024,7 +1098,7 @@ def _make_core(
             "wi": jnp.asarray(0, i32),
             "moves": jnp.asarray(0, i32),
             "iters": jnp.asarray(0, i32),
-            "rounds": jnp.asarray(0, i32),
+            "ctr": _init_counters(),
             "win": _init_windows(P, nb, win_cap),
         }
         c = lax.while_loop(cond_fn, body_fn, c0)
@@ -1036,70 +1110,71 @@ def _make_core(
         # The fold runs at full (n+1,) length so the outputs can alias the
         # donated input buffers (the scratch row n is sliced off on the
         # host; its folded value is meaningless).
-        arr_p = jnp.concatenate([arr_t, jnp.zeros((1,), f64)])
-        inp_p = jnp.concatenate([inp_t, jnp.zeros((1,), i32)])
-        rejt = c["rejt"]
-        arej = jnp.isfinite(rejt)
-        srej = inp_p >= cmax_v[c["pool"]]
-        rejm = arej | srej
-        # Rejected rows get first = finish = reject time, so the fold
-        # is one masked where over the packed f64 buffer (the output
-        # keeps the donated buffer's (n + 1, 2) shape and aliases it).
-        recf_full = jnp.where(
-            rejm[:, None],
-            jnp.where(arej, rejt, arr_p)[:, None],
-            c["rec"]["recf"],
-        )
-        rec_full = {
-            "recf": recf_full,
-            "reci": c["rec"]["reci"],
-            "pool": c["pool"],
-            "rejt": rejt,
-            "rej": rejm,
-        }
-        first = recf_full[:n, 0]
-        finish = recf_full[:n, 1]
-        out_tok = rec_full["reci"][:n, 0]
-        trunc = rec_full["reci"][:n, 2]
-        pool_c = c["pool"][:n]
+        with jax.named_scope("fold"):
+            arr_p = jnp.concatenate([arr_t, jnp.zeros((1,), f64)])
+            inp_p = jnp.concatenate([inp_t, jnp.zeros((1,), i32)])
+            rejt = c["rejt"]
+            arej = jnp.isfinite(rejt)
+            srej = inp_p >= cmax_v[c["pool"]]
+            rejm = arej | srej
+            # Rejected rows get first = finish = reject time, so the fold
+            # is one masked where over the packed f64 buffer (the output
+            # keeps the donated buffer's (n + 1, 2) shape and aliases it).
+            recf_full = jnp.where(
+                rejm[:, None],
+                jnp.where(arej, rejt, arr_p)[:, None],
+                c["rec"]["recf"],
+            )
+            rec_full = {
+                "recf": recf_full,
+                "reci": c["rec"]["reci"],
+                "pool": c["pool"],
+                "rejt": rejt,
+                "rej": rejm,
+            }
+            first = recf_full[:n, 0]
+            finish = recf_full[:n, 1]
+            out_tok = rec_full["reci"][:n, 0]
+            trunc = rec_full["reci"][:n, 2]
+            pool_c = c["pool"][:n]
 
-        compm = ~rejm[:n]
-        ttft = jnp.where(compm, first - arr_t, jnp.nan)
-        tpot = jnp.where(
-            compm & (out_tok > 1),
-            (finish - first) / jnp.maximum(out_tok - 1, 1),
-            jnp.nan,
-        )
-        out = {
-            "metrics": {
-                "completed": jnp.sum(compm),
-                "rejected": jnp.sum(rejm[:n]),
-                "truncated": jnp.sum(trunc),
-                "routed": jnp.stack(
-                    [jnp.sum(pool_c == p) for p in range(P)]
-                ),
-                "ttft_mean": jnp.nanmean(ttft),
-                "ttft_p50": jnp.nanpercentile(ttft, 50),
-                "ttft_p99": jnp.nanpercentile(ttft, 99),
-                "tpot_mean": jnp.nanmean(tpot),
-                "tpot_p99": jnp.nanpercentile(tpot, 99),
-                "t_end": jnp.max(finish),
-                "makespan": jnp.max(finish) - jnp.min(arr_t),
-            },
-            "preempt": c["pools"]["npre"],
-            "reject": c["pools"]["nrej"],
-            "truncate": c["pools"]["ntr"],
-            "th": c["th"],
-            "moves": c["moves"],
-            "nwin": c["wi"],
-            "win": c["win"],
-            "iters": c["iters"],
-            "rounds": c["rounds"],
-        }
-        if return_records:
-            # Full (n + 1,) leaves so every output can alias its donated
-            # input buffer; callers slice off the scratch row.
-            out["rec"] = rec_full
+            compm = ~rejm[:n]
+            ttft = jnp.where(compm, first - arr_t, jnp.nan)
+            tpot = jnp.where(
+                compm & (out_tok > 1),
+                (finish - first) / jnp.maximum(out_tok - 1, 1),
+                jnp.nan,
+            )
+            out = {
+                "metrics": {
+                    "completed": jnp.sum(compm),
+                    "rejected": jnp.sum(rejm[:n]),
+                    "truncated": jnp.sum(trunc),
+                    "routed": jnp.stack(
+                        [jnp.sum(pool_c == p) for p in range(P)]
+                    ),
+                    "ttft_mean": jnp.nanmean(ttft),
+                    "ttft_p50": jnp.nanpercentile(ttft, 50),
+                    "ttft_p99": jnp.nanpercentile(ttft, 99),
+                    "tpot_mean": jnp.nanmean(tpot),
+                    "tpot_p99": jnp.nanpercentile(tpot, 99),
+                    "t_end": jnp.max(finish),
+                    "makespan": jnp.max(finish) - jnp.min(arr_t),
+                },
+                "preempt": c["pools"]["npre"],
+                "reject": c["pools"]["nrej"],
+                "truncate": c["pools"]["ntr"],
+                "th": c["th"],
+                "moves": c["moves"],
+                "nwin": c["wi"],
+                "win": c["win"],
+                "iters": c["iters"],
+                **c["ctr"],
+            }
+            if return_records:
+                # Full (n + 1,) leaves so every output can alias its donated
+                # input buffer; callers slice off the scratch row.
+                out["rec"] = rec_full
         return out
 
     return core
@@ -1116,8 +1191,10 @@ def _runner(
 
     The third argument (record buffers) is donated — XLA writes the
     scatters into the caller's buffers in place."""
-    core = _make_core(spec, n, return_records, gate=not grid)
-    fn = jax.vmap(core, in_axes=(None, 0, 0)) if grid else core
+    if not grid:
+        return jax.jit(_make_core(spec, n, return_records), donate_argnums=(2,))
+    core = _make_core(spec, n, return_records, lanes="lanes")
+    fn = jax.vmap(core, in_axes=(None, 0, 0), axis_name="lanes")
     return jax.jit(fn, donate_argnums=(2,))
 
 
@@ -1128,8 +1205,79 @@ def _runner(
 #: {(spec, n, return_records, grid, g): {"lower_s", "compile_s"}}
 _COMPILE_STATS: dict = {}
 
-#: Counters from the most recent compiled run (see :func:`last_run_stats`).
+#: Counters and host spans of the most recent compiled run (see
+#: :func:`last_run_stats`).
 _LAST_RUN: dict = {}
+
+#: Host spans of one run: the parent, then its children in call order.
+_RUN_SPANS = (
+    "repro.sim.run",
+    "repro.sim.route_precompute",
+    "repro.sim.compile",
+    "repro.sim.stage",
+    "repro.sim.device_run",
+    "repro.sim.results",
+)
+
+_CALLS = itertools.count(1)
+
+
+def _run_span(mode: str):
+    """Decorate a run entry: a fresh ``_LAST_RUN`` (every span at 0 s) and
+    the parent span ``repro.sim.run`` over the whole call."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def entry(*args, **kwargs):
+            spans = dict.fromkeys(_RUN_SPANS, 0.0)
+            _LAST_RUN.clear()
+            _LAST_RUN.update(mode=mode, call=next(_CALLS), spans=spans)
+            with _span("repro.sim.run", spans, mode=mode):
+                return fn(*args, **kwargs)
+
+        return entry
+
+    return deco
+
+
+def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
+    """Copy one run's loop counters into ``_LAST_RUN``.
+
+    Lane maxima are what the device executed; ``live_slot_rounds`` and
+    ``rounds_total`` sum over lanes (``last_run_stats`` has the keys)."""
+    P = len(spec.pools)
+    I = max(ps.max_inst for ps in spec.pools)
+    S = max(ps.n_seq for ps in spec.pools)
+    _LAST_RUN.update(
+        g=g,
+        slot_rows=P * I * S,
+        iters=int(np.max(out["iters"])),
+        **{
+            k: int(np.max(out[k]))
+            for k in ("rounds", "adm_waves", "evict_runs", "evict_need")
+        },
+        rounds_total=int(np.sum(out["rounds"])),
+        live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
+    )
+
+
+@contextlib.contextmanager
+def _span(name: str, into: dict, key: Optional[str] = None, **meta):
+    """Time a block on the profiler's clock and on ``perf_counter``.
+
+    Opens a ``jax.profiler.TraceAnnotation`` (a host span in a device
+    profile, with the run's call number and ``meta``) and adds the block's
+    seconds to ``into[key or name]``, so the span and its counter come
+    from one timing. Without a profiler running it costs microseconds."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(
+            name, call=_LAST_RUN.get("call", 0), **meta
+        ):
+            yield
+    finally:
+        k = key or name
+        into[k] = into.get(k, 0.0) + (time.perf_counter() - t0)
 
 
 def _abstract_inputs(spec: _SimSpec, n: int, grid: bool, g: int):
@@ -1175,8 +1323,9 @@ def _aot(
 ):
     """AOT-compiled executable for one static shape key.
 
-    ``.lower().compile()`` runs here exactly once per key; wall-clock
-    lower/compile times land in ``_COMPILE_STATS`` so the benchmark's
+    ``.lower().compile()`` runs here exactly once per key, under the
+    host spans ``repro.sim.lower`` and ``repro.sim.backend_compile``; their
+    wall-clock times land in ``_COMPILE_STATS`` so the benchmark's
     ``jax_compile`` row can report compilation alone (no run attached).
     """
     with jax.enable_x64(), warnings.catch_warnings():
@@ -1189,26 +1338,48 @@ def _aot(
             )
         fn = _runner(spec, n, return_records, grid)
         targs, lane, rec = _abstract_inputs(spec, n, grid, g)
-        t0 = time.perf_counter()
-        lowered = fn.lower(targs, lane, rec)
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        t2 = time.perf_counter()
-    _COMPILE_STATS[(spec, n, return_records, grid, g)] = {
-        "lower_s": t1 - t0,
-        "compile_s": t2 - t1,
-    }
+        stats = {}
+        with _span("repro.sim.lower", stats, "lower_s", n=n, g=g):
+            lowered = fn.lower(targs, lane, rec)
+        with _span("repro.sim.backend_compile", stats, "compile_s", n=n, g=g):
+            compiled = lowered.compile()
+    _COMPILE_STATS[(spec, n, return_records, grid, g)] = stats
     return compiled
 
 
 def last_run_stats() -> dict:
-    """Loop counters from the most recent compiled run on this host.
+    """Loop counters and host spans of the most recent compiled run.
 
-    Keys: ``iters`` (outer epochs, coalesced bound ``n + 1``),
-    ``rounds`` (coalesced sweep rounds ≈ the pre-coalescing outer
-    iteration count), ``n``, and ``mode`` (``"fleet"``/``"grid"``; grid
-    adds ``g`` and reports per-lane maxima plus totals)."""
-    return dict(_LAST_RUN)
+    ``mode`` (``"fleet"``/``"grid"``), ``n``, ``g`` (1 for a single
+    lane), ``call`` (this process's run number, also on every span) and
+    ``slot_rows`` (P x max I x max S, the padded slot rows each round
+    works over). Loop counters, as the device executed them (under vmap
+    the lanes run in lockstep until the last is done, so a grid reports
+    the lane maximum unless noted):
+
+    * ``iters``: outer epochs (coalesced bound ``n + 1``);
+    * ``rounds``: sweep rounds (≈ the pre-coalescing outer iteration
+      count); a grid adds ``rounds_total``, summed over lanes;
+    * ``adm_waves``: trips of the admission fixpoint;
+    * ``evict_runs``: rounds in which the eviction pass ran (every round
+      on a grid, the gate's taken branch on a single lane);
+    * ``evict_need``: rounds in which some instance's growth demand
+      exceeded its free blocks (in any lane, on a grid);
+    * ``live_slot_rounds``: live decode slots after admission, summed over
+      each lane's own rounds and over lanes, so
+      ``live_slot_rounds / (g * rounds * slot_rows)`` is the share of the
+      slot rows each executed round works on that hold a live request.
+
+    ``spans`` holds the seconds of each host span of the run:
+    ``repro.sim.run`` (the whole entry call) and its children
+    ``repro.sim.route_precompute``, ``repro.sim.compile`` (getting the
+    executable: an ``_aot`` miss's lower and compile, microseconds on a
+    hit), ``repro.sim.stage`` (host input arrays), ``repro.sim.device_run``
+    (the executable call until its outputs are on the host) and
+    ``repro.sim.results`` (record unpack, back-fill, history, telemetry,
+    summaries). ``run - device_run`` is the host's own time in the call.
+    """
+    return {**_LAST_RUN, "spans": dict(_LAST_RUN.get("spans", {}))}
 
 
 def compile_stats() -> list[dict]:
@@ -1258,6 +1429,7 @@ def _carry_report(spec: _SimSpec, n: int) -> dict:
     with jax.enable_x64():
         pools = jax.eval_shape(lambda: _init_pools(spec, n))
         wins = jax.eval_shape(lambda: _init_windows(P, nb, win_cap))
+        ctr = nbytes(jax.eval_shape(_init_counters))
     rec_bytes = sum(
         (n + 1) * w * np.dtype(dt).itemsize for _, dt, w in _REC_DTYPES
     )
@@ -1267,7 +1439,7 @@ def _carry_report(spec: _SimSpec, n: int) -> dict:
         if name in ("recf", "reci", "rejt")
     )
     i4 = np.dtype(np.int32).itemsize
-    scalars = 7 * i4  # a, win_seen, win_prev, wi, moves, iters, rounds
+    scalars = 6 * i4 + ctr  # a, win_seen, win_prev, wi, moves, iters
     th_bytes = (P - 1) * i4 + P * i4  # th + prev_err
     drain_pools = nbytes({k: pools[k] for k in _DRAIN_POOL_KEYS})
     drain = (
@@ -1277,7 +1449,7 @@ def _carry_report(spec: _SimSpec, n: int) -> dict:
         + th_bytes
         + 5 * i4  # a, win_seen, win_prev, wi, moves
     )
-    sweep = nbytes(pools) + sweep_rec + i4  # + rounds
+    sweep = nbytes(pools) + sweep_rec + ctr
     outer = nbytes(pools) + nbytes(wins) + rec_bytes + th_bytes + scalars
     return {
         "carry_bytes": outer,
@@ -1464,6 +1636,7 @@ def _fleet_spec(fleet, cols: TraceColumns):
 # ---------------------------------------------------------------------------
 
 
+@_run_span("fleet")
 def run_fleet_jax(fleet, trace):
     """Execute one fleet run on the compiled backend; returns FleetResult.
 
@@ -1477,8 +1650,10 @@ def run_fleet_jax(fleet, trace):
     from repro.sim.fleet import FleetResult
     from repro.sim.metrics import summarize_columns
 
+    spans = _LAST_RUN["spans"]
     cols = _as_columns(trace)
     n = len(cols)
+    _LAST_RUN["n"] = n
     spec, ordered, shells = _fleet_spec(fleet, cols)
     P = len(spec.pools)
 
@@ -1490,21 +1665,15 @@ def run_fleet_jax(fleet, trace):
             if fleet.controller is None
             else max(1, min(fleet.epoch, fleet.control_window))
         )
-        budgets, final_state = precompute_budget_trajectory(
-            cols, router.calibrator, epoch_cap=epoch_cap
-        )
+        with _span("repro.sim.route_precompute", spans, n=n, g=1):
+            budgets, final_state = precompute_budget_trajectory(
+                cols, router.calibrator, epoch_cap=epoch_cap
+            )
         router.calibrator.load_state(final_state)
         th0 = [int(b) for b in router.pools.thresholds]
     else:
         th0 = []
 
-    lane = {
-        "th": np.asarray(th0, np.int32),
-        "ninst": np.asarray(
-            [fleet.pools[name].num_instances for name in ordered], np.int32
-        ),
-        "ctrl": _ctrl_params(fleet.controller, enabled=True),
-    }
     if self_telemetry := fleet.telemetry:
         self_telemetry.set_trace(
             cols.byte_len, cols.category, cols.true_input_tokens,
@@ -1527,65 +1696,71 @@ def run_fleet_jax(fleet, trace):
         )
 
     with jax.enable_x64():
-        exe = _aot(spec, n, True, False, 0)
-        out = exe(_trace_arrays(cols, budgets), lane, _fresh_records(n))
-        out = jax.tree_util.tree_map(np.asarray, out)
-    _LAST_RUN.clear()
-    _LAST_RUN.update(
-        mode="fleet",
-        n=n,
-        iters=int(out["iters"]),
-        rounds=int(out["rounds"]),
-    )
+        with _span("repro.sim.compile", spans, n=n, g=1):
+            exe = _aot(spec, n, True, False, 0)
+        with _span("repro.sim.stage", spans, n=n, g=1):
+            lane = {
+                "th": np.asarray(th0, np.int32),
+                "ninst": np.asarray(
+                    [fleet.pools[name].num_instances for name in ordered],
+                    np.int32,
+                ),
+                "ctrl": _ctrl_params(fleet.controller, enabled=True),
+            }
+            args = (_trace_arrays(cols, budgets), lane, _fresh_records(n))
+        with _span("repro.sim.device_run", spans, n=n, g=1):
+            out = jax.tree_util.tree_map(np.asarray, exe(*args))
 
-    rec = _unpack_records(out["rec"], n)
-    ids = np.asarray(cols.request_id, np.int64)
-    arr = np.asarray(cols.arrival_time, np.float64)
-    fleet_cols = {
-        "request_id": ids,
-        "arrival": arr,
-        "first_token": rec["first"],
-        "finish": rec["finish"],
-        "output_tokens": rec["out"].astype(np.int64),
-        "preemptions": rec["pre"].astype(np.int64),
-        "truncated": rec["trunc"],
-        "rejected": rec["rej"],
-    }
-    per_pool_cols = {}
-    for idx, name in enumerate(ordered):
-        m = rec["pool"] == idx
-        pc = {k: v[m] for k, v in fleet_cols.items()}
-        per_pool_cols[name] = pc
-        shell = shells[idx]
-        shell._records.add_bulk(*(pc[k] for k, _ in shell._records.COLUMNS))
-        shell.preemption_count = int(out["preempt"][idx])
-        shell.rejection_count = int(out["reject"][idx])
-        shell.truncation_count = int(out["truncate"][idx])
-        if router is not None:
-            router.routed[name] += int(out["metrics"]["routed"][idx])
+    with _span("repro.sim.results", spans, n=n, g=1):
+        _note_counters(out, spec, 1)
+        rec = _unpack_records(out["rec"], n)
+        ids = np.asarray(cols.request_id, np.int64)
+        arr = np.asarray(cols.arrival_time, np.float64)
+        fleet_cols = {
+            "request_id": ids,
+            "arrival": arr,
+            "first_token": rec["first"],
+            "finish": rec["finish"],
+            "output_tokens": rec["out"].astype(np.int64),
+            "preemptions": rec["pre"].astype(np.int64),
+            "truncated": rec["trunc"],
+            "rejected": rec["rej"],
+        }
+        per_pool_cols = {}
+        for idx, name in enumerate(ordered):
+            m = rec["pool"] == idx
+            pc = {k: v[m] for k, v in fleet_cols.items()}
+            per_pool_cols[name] = pc
+            shell = shells[idx]
+            shell._records.add_bulk(*(pc[k] for k, _ in shell._records.COLUMNS))
+            shell.preemption_count = int(out["preempt"][idx])
+            shell.rejection_count = int(out["reject"][idx])
+            shell.truncation_count = int(out["truncate"][idx])
+            if router is not None:
+                router.routed[name] += int(out["metrics"]["routed"][idx])
 
-    final_th = [int(b) for b in out["th"][: P - 1]]
-    if router is not None and fleet.controller is not None:
-        router.pools.set_thresholds(final_th)
-        _synthesize_history(fleet.controller, out, th0)
+        final_th = [int(b) for b in out["th"][: P - 1]]
+        if router is not None and fleet.controller is not None:
+            router.pools.set_thresholds(final_th)
+            _synthesize_history(fleet.controller, out, th0)
 
-    t_end = float(out["metrics"]["t_end"])
-    if fleet.telemetry is not None:
-        _replay_telemetry(fleet, ordered, shells, spec, out, n, t_end, final_th)
+        t_end = float(out["metrics"]["t_end"])
+        if fleet.telemetry is not None:
+            _replay_telemetry(fleet, ordered, shells, spec, out, n, t_end, final_th)
 
-    return FleetResult(
-        summary=summarize_columns("fleet", fleet_cols),
-        per_pool={
-            name: summarize_columns(name, c)
-            for name, c in per_pool_cols.items()
-        },
-        router_stats=router.stats() if router else {},
-        preemptions=int(out["preempt"].sum()),
-        rejections=int(out["reject"].sum()),
-        truncations=int(out["truncate"].sum()),
-        telemetry=fleet.telemetry,
-        slo=fleet.slo,
-    )
+        return FleetResult(
+            summary=summarize_columns("fleet", fleet_cols),
+            per_pool={
+                name: summarize_columns(name, c)
+                for name, c in per_pool_cols.items()
+            },
+            router_stats=router.stats() if router else {},
+            preemptions=int(out["preempt"].sum()),
+            rejections=int(out["reject"].sum()),
+            truncations=int(out["truncate"].sum()),
+            telemetry=fleet.telemetry,
+            slo=fleet.slo,
+        )
 
 
 def _synthesize_history(controller, out, th0):
@@ -1713,6 +1888,7 @@ def _broadcast_axis(values, g: int, name: str):
     return list(values)
 
 
+@_run_span("grid")
 def run_fleet_grid(
     trace,
     pools: dict[str, tuple[PoolConfig, int]],
@@ -1748,8 +1924,10 @@ def run_fleet_grid(
     shares the same budget array and the sweep stays exact w.r.t. the
     single-lane jax backend (asserted by the grid-parity test).
     """
+    spans = _LAST_RUN["spans"]
     cols = _as_columns(trace)
     n = len(cols)
+    _LAST_RUN["n"] = n
     if n == 0:
         raise ValueError("run_fleet_grid needs a non-empty trace")
 
@@ -1819,43 +1997,41 @@ def run_fleet_grid(
         epoch_cap = (
             max(1, min(epoch, control_window)) if any_ctrl else epoch
         )
-        budgets, _ = precompute_budget_trajectory(cols, cal, epoch_cap=epoch_cap)
+        with _span("repro.sim.route_precompute", spans, n=n, g=g):
+            budgets, _ = precompute_budget_trajectory(
+                cols, cal, epoch_cap=epoch_cap
+            )
 
-    lane = {"th": th_arr, "ninst": inst_arr, "ctrl": ctrl}
     with jax.enable_x64():
-        exe = _aot(spec, n, return_records, True, g)
-        out = exe(_trace_arrays(cols, budgets), lane, _fresh_records(n, g))
-        out = jax.tree_util.tree_map(np.asarray, out)
-    _LAST_RUN.clear()
-    _LAST_RUN.update(
-        mode="grid",
-        n=n,
-        g=g,
-        iters=int(out["iters"].max()),
-        rounds=int(out["rounds"].max()),
-        iters_total=int(out["iters"].sum()),
-        rounds_total=int(out["rounds"].sum()),
-    )
+        with _span("repro.sim.compile", spans, n=n, g=g):
+            exe = _aot(spec, n, return_records, True, g)
+        with _span("repro.sim.stage", spans, n=n, g=g):
+            lane = {"th": th_arr, "ninst": inst_arr, "ctrl": ctrl}
+            args = (_trace_arrays(cols, budgets), lane, _fresh_records(n, g))
+        with _span("repro.sim.device_run", spans, n=n, g=g):
+            out = jax.tree_util.tree_map(np.asarray, exe(*args))
 
-    m = out["metrics"]
-    return FleetGridResult(
-        pool_names=names,
-        thresholds=th_arr,
-        instances=inst_arr,
-        completed=m["completed"].astype(np.int64),
-        rejected=m["rejected"].astype(np.int64),
-        truncated=m["truncated"].astype(np.int64),
-        preemptions=out["preempt"].sum(axis=1).astype(np.int64),
-        routed=m["routed"].astype(np.int64),
-        ttft_mean=m["ttft_mean"],
-        ttft_p50=m["ttft_p50"],
-        ttft_p99=m["ttft_p99"],
-        tpot_mean=m["tpot_mean"],
-        tpot_p99=m["tpot_p99"],
-        makespan=m["makespan"],
-        final_thresholds=out["th"].reshape(g, P - 1)[:, : P - 1],
-        controller_moves=out["moves"].astype(np.int64),
-        records=(
-            _unpack_records(out["rec"], n) if "rec" in out else None
-        ),
-    )
+    with _span("repro.sim.results", spans, n=n, g=g):
+        _note_counters(out, spec, g)
+        m = out["metrics"]
+        return FleetGridResult(
+            pool_names=names,
+            thresholds=th_arr,
+            instances=inst_arr,
+            completed=m["completed"].astype(np.int64),
+            rejected=m["rejected"].astype(np.int64),
+            truncated=m["truncated"].astype(np.int64),
+            preemptions=out["preempt"].sum(axis=1).astype(np.int64),
+            routed=m["routed"].astype(np.int64),
+            ttft_mean=m["ttft_mean"],
+            ttft_p50=m["ttft_p50"],
+            ttft_p99=m["ttft_p99"],
+            tpot_mean=m["tpot_mean"],
+            tpot_p99=m["tpot_p99"],
+            makespan=m["makespan"],
+            final_thresholds=out["th"].reshape(g, P - 1)[:, : P - 1],
+            controller_moves=out["moves"].astype(np.int64),
+            records=(
+                _unpack_records(out["rec"], n) if "rec" in out else None
+            ),
+        )
