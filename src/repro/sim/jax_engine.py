@@ -89,8 +89,8 @@ Instrumentation
 ---------------
 Always on. Inside the executable, each round phase runs under a
 ``jax.named_scope`` (``_make_core``), and the carry counts admission
-waves, eviction passes, eviction need and live slot rows as the device
-executed them. On the host, each entry call is the span
+waves, record-write trips, eviction passes, eviction need and live slot
+rows as the device executed them. On the host, each entry call is the span
 ``repro.sim.run`` with children for routing precompute, compilation,
 staging, the device run and result assembly: ``TraceAnnotation`` events
 on a device profile's clock, whose seconds also land in
@@ -167,19 +167,25 @@ _BIG_I = 1 << 30
 _BIG_F = 1.0e18
 
 #: Donated record buffers (name, dtype, width). Same-dtype columns are
-#: packed along a trailing width axis so each completion round issues
-#: one scatter per buffer instead of one per column — XLA:CPU charges
-#: ~40 µs per batched scatter inside a while body regardless of row
-#: width. ``recf`` packs [first_token, finish]; ``reci`` packs
-#: [out_tokens, preemptions, truncated(0/1)]. ``rejt`` stages the
-#: admission-reject timestamp (+inf = not rejected); the boolean ``rej``
-#: column is derived post-loop, so it never rides a loop carry.
+#: packed along a trailing width axis, so one record write is one row of
+#: each buffer: a TPU v5e pays a scatter by the rows it writes (about
+#: 37.5 ns a row, in and out of vmap), not by its columns. ``recf`` packs
+#: [first_token, finish]; ``reci`` packs [out_tokens, preemptions,
+#: truncated(0/1)]. ``rejt`` stages the admission-reject timestamp (+inf =
+#: not rejected); the boolean ``rej`` column is derived post-loop, so it
+#: never rides a loop carry.
 _REC_DTYPES = (
     ("recf", np.float64, 2),
     ("reci", np.int32, 3),
     ("pool", np.int32, 1),
     ("rejt", np.float64, 1),
 )
+
+#: Completion records one write trip stores. A round writes its completing
+#: slots in ``ceil(completions / K)`` trips of K rows (K is this, or the
+#: fleet's real slot count if smaller); a round completes about one
+#: request a lane, so one trip of 32 rows replaces a write of every slot.
+_REC_TRIP_ROWS = 32
 
 #: Per-pool state that the arrival drain actually mutates (FIFO lists,
 #: load-balance picks, wake seeding, submit-reject counter). Everything
@@ -308,6 +314,7 @@ def _init_counters() -> dict:
     return {
         "rounds": z,
         "adm_waves": z,
+        "rec_trips": z,
         "evict_runs": z,
         "evict_need": z,
         "live_slot_rounds": jnp.asarray(0, jnp.int64),
@@ -395,8 +402,9 @@ def _make_core(
     device profile's per-op events group by phase. The loop counters
     (``ctr`` in the carry, returned in ``out``) count what the device
     executed: under ``vmap`` a batched ``while`` runs until its last
-    lane is done, so the admission trip count and the eviction need are
-    ``lax.pmax``-ed over ``lanes`` (the identity for a single lane).
+    lane is done, so the admission and record-write trip counts and the
+    eviction need are ``lax.pmax``-ed over ``lanes`` (the identity for a
+    single lane).
     """
     P = len(spec.pools)
     win = spec.win_size
@@ -419,6 +427,19 @@ def _make_core(
     )
     pg2 = jnp.arange(P)[:, None]
     ig2 = jnp.arange(I)[None, :]
+    # Every pool's real ``(max_inst, n_seq)`` slot block, flattened in
+    # pool order: ``real_rows[j]`` is the j-th real slot's index in the
+    # flattened padded ``(P, I, S)`` state. Completion records are ranked
+    # over these rows alone, so padding never costs a record write.
+    real_rows = np.concatenate(
+        [
+            ((p * I + np.arange(ps.max_inst)[:, None]) * S
+             + np.arange(ps.n_seq)[None, :]).ravel()
+            for p, ps in enumerate(spec.pools)
+        ]
+    ).astype(np.int32)
+    R = real_rows.size
+    K = min(_REC_TRIP_ROWS, R)
 
     _advance_1 = functools.partial(decode_advance_jnp, w=W, h=H, chunk=CHUNK)
 
@@ -816,34 +837,55 @@ def _make_core(
                 ntr = st["ntr"] + jnp.sum(trunc_n, axis=(1, 2), dtype=i32)
 
             with jax.named_scope("record_scatter"):
-                # One scatter per pool per packed record buffer, over that
-                # pool's *real* ``(max_inst, n_seq)`` slot block. The
-                # stacked arrays are padded to ``(P, max I, max S)``, and
-                # XLA:CPU lowers a batched scatter to a serial per-row
-                # loop, so scattering the padded block pays for slots that
-                # can never complete (a ragged 4×128 + 12×16 topology pads
-                # 4.4×). Request ids are globally unique, so per-pool
-                # updates stay disjoint; non-completing slots hit the
-                # scratch row. Packing same-dtype columns keeps this at
-                # two scatter ops per pool per round instead of five.
-                ridx = jnp.where(comp, st["rid"], n)
-                recf_new = jnp.stack(
-                    [ft_a, jnp.broadcast_to(end[:, :, None], (P, I, S))],
-                    axis=-1,
+                # Write the records of the completing slots only. A TPU
+                # pays a scatter by the rows it writes (about 37.5 ns a
+                # row on a v5e, completing or not), and a round completes
+                # about one request a lane against thousands of slots. So
+                # the completing real slots are ranked by an inclusive
+                # prefix count, and trip t writes ranks [tK, tK + K): the
+                # rank-r slot is the real row at the count of rows whose
+                # prefix count is <= r (a compare-all count; ``nonzero``
+                # with a size lowers to a scatter over every row). Ranks
+                # past a lane's completions aim at the scratch row n.
+                # Request ids are globally unique, so real writes stay
+                # disjoint. Under vmap the trip count is the lane maximum,
+                # so the loop's bound is shared and no lane selects its
+                # buffers per trip.
+                done = jnp.concatenate(
+                    [
+                        comp[p, : ps.max_inst, : ps.n_seq].reshape(-1)
+                        for p, ps in enumerate(spec.pools)
+                    ]
                 )
-                reci_new = jnp.stack(
-                    [gen_a, st["pc"], tr_a.astype(i32)], axis=-1
+                rank = jnp.cumsum(done, dtype=i32)
+                ndone = rank[-1]
+                trips = executed((ndone + K - 1) // K)
+                cols_f = (ft_a.reshape(-1), end.reshape(-1))
+                cols_i = (
+                    gen_a.reshape(-1),
+                    st["pc"].reshape(-1),
+                    tr_a.astype(i32).reshape(-1),
                 )
-                rf, ri = rec["recf"], rec["reci"]
-                for p in range(P):
-                    ip, sp = spec.pools[p].max_inst, spec.pools[p].n_seq
-                    idx_p = ridx[p, :ip, :sp]
-                    rf = rf.at[idx_p].set(
-                        recf_new[p, :ip, :sp], mode="promise_in_bounds"
+                rid_f = st["rid"].reshape(-1)
+
+                def write_trip(t, bufs):
+                    r = t * K + jnp.arange(K, dtype=i32)
+                    at = jnp.sum(rank[None, :] <= r[:, None], axis=1, dtype=i32)
+                    j = jnp.asarray(real_rows)[jnp.minimum(at, R - 1)]
+                    ridx = jnp.where(r < ndone, rid_f[j], n)
+                    rf = bufs[0].at[ridx].set(
+                        jnp.stack([cols_f[0][j], cols_f[1][j // S]], axis=-1),
+                        mode="promise_in_bounds",
                     )
-                    ri = ri.at[idx_p].set(
-                        reci_new[p, :ip, :sp], mode="promise_in_bounds"
+                    ri = bufs[1].at[ridx].set(
+                        jnp.stack([c[j] for c in cols_i], axis=-1),
+                        mode="promise_in_bounds",
                     )
+                    return rf, ri
+
+                rf, ri = lax.fori_loop(
+                    0, trips, write_trip, (rec["recf"], rec["reci"])
+                )
                 rec = {"recf": rf, "reci": ri}
 
             with jax.named_scope("evict"):
@@ -999,6 +1041,7 @@ def _make_core(
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
+                    "rec_trips": ctr["rec_trips"] + trips,
                     "evict_runs": ctr["evict_runs"] + ran,
                     "evict_need": ctr["evict_need"]
                     + executed(need.astype(i32)),
@@ -1254,7 +1297,8 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
         iters=int(np.max(out["iters"])),
         **{
             k: int(np.max(out[k]))
-            for k in ("rounds", "adm_waves", "evict_runs", "evict_need")
+            for k in ("rounds", "adm_waves", "rec_trips", "evict_runs",
+                      "evict_need")
         },
         rounds_total=int(np.sum(out["rounds"])),
         live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
@@ -1361,6 +1405,8 @@ def last_run_stats() -> dict:
     * ``rounds``: sweep rounds (≈ the pre-coalescing outer iteration
       count); a grid adds ``rounds_total``, summed over lanes;
     * ``adm_waves``: trips of the admission fixpoint;
+    * ``rec_trips``: record-write trips (each writes up to 32 completing
+      slots' records; a round with no completion runs none);
     * ``evict_runs``: rounds in which the eviction pass ran (every round
       on a grid, the gate's taken branch on a single lane);
     * ``evict_need``: rounds in which some instance's growth demand

@@ -94,8 +94,8 @@ def grids():
     return both, alone
 
 
-COUNTERS = ("iters", "rounds", "adm_waves", "evict_runs", "evict_need",
-            "live_slot_rounds", "slot_rows", "rounds_total")
+COUNTERS = ("iters", "rounds", "adm_waves", "rec_trips", "evict_runs",
+            "evict_need", "live_slot_rounds", "slot_rows", "rounds_total")
 
 
 def test_counters_exist_in_both_modes(calm, grids):
@@ -140,6 +140,26 @@ def test_grid_counts_the_waves_the_device_executed(grids):
     assert both["rounds_total"] == sum(one["rounds"] for _, one in alone)
     assert both["live_slot_rounds"] == sum(
         one["live_slot_rounds"] for _, one in alone)
+
+
+@pytest.mark.parametrize("which", ["calm", "pressed", "grid"])
+def test_record_trips_at_most_one_a_round(calm, pressed, grids, which):
+    """No round of these traces completes more than one trip's rows, so a
+    round runs at most one record-write trip; and each trip a lane runs
+    writes at least one of its n requests, each of which completes once."""
+    stats = {"calm": calm[1], "pressed": pressed[1], "grid": grids[0][1]}[which]
+    assert 0 < stats["rec_trips"] <= stats["rounds"]
+    assert stats["rec_trips"] <= stats["g"] * stats["n"]
+
+
+def test_grid_counts_the_record_trips_the_device_executed(grids):
+    """Lockstep: a round's trips run until the lane with the most
+    completions is done, so two lanes execute at least the trips of either
+    lane alone and at most their sum."""
+    (_, both), alone = grids
+    trips = [one["rec_trips"] for _, one in alone]
+    assert max(trips) <= both["rec_trips"] <= sum(trips)
+    assert min(trips) > 0
 
 
 @pytest.mark.parametrize("which", ["calm", "pressed", "grid"])

@@ -890,6 +890,79 @@ class TestJaxBackendEquivalence:
             )
 
 
+class TestJaxRecordTrips:
+    """The compiled loop writes a round's completion records in trips of
+    ``_REC_TRIP_ROWS`` (32) rows. A burst of three requests for each of
+    40 instances, each of which finishes one request a round, completes
+    40 requests in each of its three rounds: each round needs a second,
+    part-filled trip. The records must equal the vectorized engine's,
+    lane by lane."""
+
+    POOLS = {
+        "short": (PoolConfig("short", 2048, 4), 24),
+        "long": (PoolConfig("long", 8192, 4), 16),
+    }
+    THRESHOLDS = (2048, 1024)  # both route the 40 large prompts long
+
+    @staticmethod
+    def burst(n=120):
+        return [
+            Request(
+                request_id=i,
+                byte_len=40_000 if i % 5 < 2 else 8,
+                max_output_tokens=1,
+                category=0,
+                arrival_time=0.0,
+                true_input_tokens=1,
+                true_output_tokens=1,
+            )
+            for i in range(n)
+        ]
+
+    @pytest.mark.parametrize("mode", ["single", "grid"])
+    def test_burst_records_match_across_trips(self, mode):
+        from collections import Counter
+
+        from repro.sim import jax_engine
+
+        trace = self.burst()
+        lanes = self.THRESHOLDS if mode == "grid" else self.THRESHOLDS[:1]
+        want = []
+        for th in lanes:
+            sim = FleetSim(dict(self.POOLS), DYADIC, backend="vectorized",
+                           coalesce_dt=0.0, spillover=False, thresholds=[th])
+            want.append(record_tuples(sim.run(trace), sim))
+        if mode == "single":
+            sim = FleetSim(dict(self.POOLS), DYADIC, backend="jax",
+                           spillover=False, thresholds=list(lanes))
+            got = [record_tuples(sim.run(trace), sim)]
+        else:
+            grid = jax_engine.run_fleet_grid(
+                trace, dict(self.POOLS), DYADIC,
+                thresholds=[[th] for th in lanes], return_records=True)
+            rec = grid.records
+            got = [
+                sorted(
+                    (r.request_id, r.arrival_time, rec["first"][k, j],
+                     rec["finish"][k, j], int(rec["out"][k, j]),
+                     int(rec["pre"][k, j]), bool(rec["trunc"][k, j]),
+                     bool(rec["rej"][k, j]))
+                    for j, r in enumerate(trace)
+                )
+                for k in range(len(lanes))
+            ]
+        assert got == want
+        # every instance finishes one request a round, at one time
+        per_round = [Counter(t[3] for t in w) for w in want]
+        assert sorted(per_round[0].values()) == [40, 40, 40]
+        K = jax_engine._REC_TRIP_ROWS
+        trips = max(sum(-(-c // K) for c in pr.values()) for pr in per_round)
+        stats = jax_engine.last_run_stats()
+        assert stats["mode"] == ("grid" if mode == "grid" else "fleet")
+        assert stats["rec_trips"] == trips == 6
+        assert stats["rounds"] == 3
+
+
 class TestJaxRoutedTolerance:
     """Routed fleets on the jax backend precompute EMA budgets host-side in
     arrival order (the device loop only does a searchsorted per dispatch),
