@@ -318,6 +318,7 @@ def _init_counters() -> dict:
         "evict_runs": z,
         "evict_need": z,
         "live_slot_rounds": jnp.asarray(0, jnp.int64),
+        "live_peak": z,
     }
 
 
@@ -1036,8 +1037,10 @@ def _make_core(
                 }
 
                 # Loop counters: scalars beside the state update (the live
-                # slot sum is one (P, I) reduce of the post-admission nact).
+                # slot sum is one (P, I) reduce of the post-admission nact,
+                # and the peak is a max beside it).
                 ran = need.astype(i32) if gate else 1
+                live = jnp.sum(nact, dtype=i32)
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
@@ -1046,7 +1049,8 @@ def _make_core(
                     "evict_need": ctr["evict_need"]
                     + executed(need.astype(i32)),
                     "live_slot_rounds": ctr["live_slot_rounds"]
-                    + jnp.sum(nact, dtype=jnp.int64),
+                    + live.astype(jnp.int64),
+                    "live_peak": jnp.maximum(ctr["live_peak"], live),
                 }
             return st, rec, rejt, ctr
 
@@ -1175,35 +1179,41 @@ def _make_core(
                 "rejt": rejt,
                 "rej": rejm,
             }
-            first = recf_full[:n, 0]
             finish = recf_full[:n, 1]
-            out_tok = rec_full["reci"][:n, 0]
             trunc = rec_full["reci"][:n, 2]
             pool_c = c["pool"][:n]
 
             compm = ~rejm[:n]
-            ttft = jnp.where(compm, first - arr_t, jnp.nan)
-            tpot = jnp.where(
-                compm & (out_tok > 1),
-                (finish - first) / jnp.maximum(out_tok - 1, 1),
-                jnp.nan,
-            )
+            metrics = {
+                "completed": jnp.sum(compm),
+                "rejected": jnp.sum(rejm[:n]),
+                "truncated": jnp.sum(trunc),
+                "routed": jnp.stack([jnp.sum(pool_c == p) for p in range(P)]),
+                "t_end": jnp.max(finish),
+                "makespan": jnp.max(finish) - jnp.min(arr_t),
+            }
+            if not gate:
+                # Latency summaries for FleetGridResult. A single lane's
+                # host summarizes its own records instead, and skipping
+                # the percentiles' float64 sorts there matters: at 60,000
+                # rows they added about a minute to a TPU v5e's compile.
+                first = recf_full[:n, 0]
+                out_tok = rec_full["reci"][:n, 0]
+                ttft = jnp.where(compm, first - arr_t, jnp.nan)
+                tpot = jnp.where(
+                    compm & (out_tok > 1),
+                    (finish - first) / jnp.maximum(out_tok - 1, 1),
+                    jnp.nan,
+                )
+                metrics.update(
+                    ttft_mean=jnp.nanmean(ttft),
+                    ttft_p50=jnp.nanpercentile(ttft, 50),
+                    ttft_p99=jnp.nanpercentile(ttft, 99),
+                    tpot_mean=jnp.nanmean(tpot),
+                    tpot_p99=jnp.nanpercentile(tpot, 99),
+                )
             out = {
-                "metrics": {
-                    "completed": jnp.sum(compm),
-                    "rejected": jnp.sum(rejm[:n]),
-                    "truncated": jnp.sum(trunc),
-                    "routed": jnp.stack(
-                        [jnp.sum(pool_c == p) for p in range(P)]
-                    ),
-                    "ttft_mean": jnp.nanmean(ttft),
-                    "ttft_p50": jnp.nanpercentile(ttft, 50),
-                    "ttft_p99": jnp.nanpercentile(ttft, 99),
-                    "tpot_mean": jnp.nanmean(tpot),
-                    "tpot_p99": jnp.nanpercentile(tpot, 99),
-                    "t_end": jnp.max(finish),
-                    "makespan": jnp.max(finish) - jnp.min(arr_t),
-                },
+                "metrics": metrics,
                 "preempt": c["pools"]["npre"],
                 "reject": c["pools"]["nrej"],
                 "truncate": c["pools"]["ntr"],
@@ -1294,11 +1304,12 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
     _LAST_RUN.update(
         g=g,
         slot_rows=P * I * S,
+        real_slot_rows=sum(ps.max_inst * ps.n_seq for ps in spec.pools),
         iters=int(np.max(out["iters"])),
         **{
             k: int(np.max(out[k]))
             for k in ("rounds", "adm_waves", "rec_trips", "evict_runs",
-                      "evict_need")
+                      "evict_need", "live_peak")
         },
         rounds_total=int(np.sum(out["rounds"])),
         live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
@@ -1395,11 +1406,12 @@ def last_run_stats() -> dict:
     """Loop counters and host spans of the most recent compiled run.
 
     ``mode`` (``"fleet"``/``"grid"``), ``n``, ``g`` (1 for a single
-    lane), ``call`` (this process's run number, also on every span) and
+    lane), ``call`` (this process's run number, also on every span),
     ``slot_rows`` (P x max I x max S, the padded slot rows each round
-    works over). Loop counters, as the device executed them (under vmap
-    the lanes run in lockstep until the last is done, so a grid reports
-    the lane maximum unless noted):
+    works over) and ``real_slot_rows`` (the sum over pools of instances x
+    ``n_seq``, with a grid's lane maximum of instances). Loop counters, as
+    the device executed them (under vmap the lanes run in lockstep until
+    the last is done, so a grid reports the lane maximum unless noted):
 
     * ``iters``: outer epochs (coalesced bound ``n + 1``);
     * ``rounds``: sweep rounds (≈ the pre-coalescing outer iteration
@@ -1414,7 +1426,13 @@ def last_run_stats() -> dict:
     * ``live_slot_rounds``: live decode slots after admission, summed over
       each lane's own rounds and over lanes, so
       ``live_slot_rounds / (g * rounds * slot_rows)`` is the share of the
-      slot rows each executed round works on that hold a live request.
+      slot rows each executed round works on that hold a live request;
+    * ``live_peak``: the most live decode slots after admission in any
+      executed round, summed over the fleet (a grid's lane maximum), so
+      ``live_peak / real_slot_rows`` is how full the fleet got. A round
+      sums instances that stand at their own clocks inside a sweep, so
+      this can differ by a few requests from the most requests live at
+      one instant.
 
     ``spans`` holds the seconds of each host span of the run:
     ``repro.sim.run`` (the whole entry call) and its children
@@ -1912,6 +1930,7 @@ class FleetGridResult:
     makespan: np.ndarray  # (G,) max finish − min arrival
     final_thresholds: np.ndarray  # (G, P-1) post-controller vectors
     controller_moves: np.ndarray  # (G,)
+    live_peak: np.ndarray  # (G,) most live decode slots in any round
     #: (G, n) per-request record arrays when ``return_records=True``.
     records: Optional[dict] = None
 
@@ -2077,6 +2096,7 @@ def run_fleet_grid(
             makespan=m["makespan"],
             final_thresholds=out["th"].reshape(g, P - 1)[:, : P - 1],
             controller_moves=out["moves"].astype(np.int64),
+            live_peak=out["live_peak"].astype(np.int64),
             records=(
                 _unpack_records(out["rec"], n) if "rec" in out else None
             ),
