@@ -385,12 +385,15 @@ def _make_core(
     ~P× as much.
 
     ``lanes`` is the ``vmap`` axis name in grid mode and ``None`` for a
-    single lane. A single lane short-circuits the eviction pass with
-    ``lax.cond`` (the gate) when no instance is over budget; the skipped
-    branch is bit-identical to the masked pass (``jsel = 0`` evicts
-    nothing), so gating never changes results — but under ``vmap`` a
-    batched ``cond`` runs both branches anyway, so grids run the pass
-    ungated. The mode also selects the outer-loop shape: nested
+    single lane. The eviction pass runs under ``lax.cond`` (the gate)
+    only on rounds where some instance is over budget — in any lane, on
+    a grid: the predicate is ``lax.pmax``-ed over ``lanes``, so it is
+    unbatched and the ``cond`` stays a branch under ``vmap`` (a batched
+    predicate would turn it into a select that runs both sides). The
+    skipped branch is bit-identical to the masked pass (``jsel = 0``
+    evicts nothing), and so is the pass for a lane with no need on a
+    round where another lane has some, so gating never changes
+    results. The mode also selects the outer-loop shape: nested
     drain→sweep epochs for the single-lane path, drain + exactly one
     round per outer iteration for vmapped grids (a nested sweep loop
     would run to the max round count over lanes per epoch — a measured
@@ -988,13 +991,12 @@ def _make_core(
                         st["vpc"],
                     )
 
-                need = jnp.any(demand > free1)
-                if gate:
-                    evict, nevict, vrid, vinp, vpc = lax.cond(
-                        need, evict_pass, no_evict, None
-                    )
-                else:
-                    evict, nevict, vrid, vinp, vpc = evict_pass(None)
+                # the lane maximum on a grid: unbatched, so vmap keeps the
+                # ``cond`` a branch instead of a select that runs both sides
+                need = executed(jnp.any(demand > free1).astype(i32)) > 0
+                evict, nevict, vrid, vinp, vpc = lax.cond(
+                    need, evict_pass, no_evict, None
+                )
                 npre = st["npre"] + jnp.sum(evict, axis=(1, 2), dtype=i32)
                 free1 = free1 + jnp.sum(
                     jnp.where(evict, blk0, 0), axis=2, dtype=i32
@@ -1039,15 +1041,14 @@ def _make_core(
                 # Loop counters: scalars beside the state update (the live
                 # slot sum is one (P, I) reduce of the post-admission nact,
                 # and the peak is a max beside it).
-                ran = need.astype(i32) if gate else 1
+                ran = need.astype(i32)
                 live = jnp.sum(nact, dtype=i32)
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
                     "rec_trips": ctr["rec_trips"] + trips,
                     "evict_runs": ctr["evict_runs"] + ran,
-                    "evict_need": ctr["evict_need"]
-                    + executed(need.astype(i32)),
+                    "evict_need": ctr["evict_need"] + ran,
                     "live_slot_rounds": ctr["live_slot_rounds"]
                     + live.astype(jnp.int64),
                     "live_peak": jnp.maximum(ctr["live_peak"], live),
@@ -1419,8 +1420,8 @@ def last_run_stats() -> dict:
     * ``adm_waves``: trips of the admission fixpoint;
     * ``rec_trips``: record-write trips (each writes up to 32 completing
       slots' records; a round with no completion runs none);
-    * ``evict_runs``: rounds in which the eviction pass ran (every round
-      on a grid, the gate's taken branch on a single lane);
+    * ``evict_runs``: rounds in which the gate ran the eviction pass
+      (on a grid, for every lane at once when any lane needed it);
     * ``evict_need``: rounds in which some instance's growth demand
       exceeded its free blocks (in any lane, on a grid);
     * ``live_slot_rounds``: live decode slots after admission, summed over

@@ -123,11 +123,99 @@ def test_single_lane_under_pressure_runs_the_gated_pass(pressed):
     assert stats["evict_runs"] < stats["rounds"]
 
 
-def test_grid_runs_the_pass_every_round(grids):
+def test_grid_gates_the_pass(grids):
+    """No lane of the calm grid is ever over budget, so the lane-shared
+    gate never runs the pass, whether the lanes run together or alone."""
     (_, both), alone = grids
     for stats in [both] + [s for _, s in alone]:
-        assert stats["evict_runs"] == stats["rounds"]
-        assert stats["evict_need"] == 0
+        assert stats["evict_runs"] == stats["evict_need"] == 0
+
+
+def _records(sim):
+    """{request_id: record tuple} of a FleetSim run."""
+    out = {}
+    for pool in sim.pools.values():
+        a = pool.record_arrays()
+        for j, rid in enumerate(a["request_id"]):
+            out[int(rid)] = (a["first_token"][j], a["finish"][j],
+                             int(a["output_tokens"][j]),
+                             int(a["preemptions"][j]),
+                             bool(a["truncated"][j]), bool(a["rejected"][j]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def mixed():
+    """A grid whose lanes differ in KV pressure: the same long-request
+    trace on one instance (over its block budget: it preempts) and on four
+    (never over budget); and each lane as its own FleetSim run. The pool's
+    budget is capped at 65,536 blocks, half of what its 64 slots could
+    grow to."""
+    trace = _trace(120, 100.0, 5, (8000, 16000), (8000, 16000))
+    cfg = PoolConfig("p", 65_536, 64)
+    grid = jax_engine.run_fleet_grid(trace, {"p": (cfg, 4)}, DYADIC,
+                                     instances=[[1], [4]],
+                                     return_records=True)
+    stats = jax_engine.last_run_stats()
+    singles = []
+    for inst in (1, 4):
+        sim = FleetSim({"p": (cfg, inst)}, DYADIC, backend="jax",
+                       coalesce_dt=0.0)
+        res = sim.run(trace)
+        singles.append((res, _records(sim), jax_engine.last_run_stats()))
+    return trace, grid, stats, singles
+
+
+def test_mixed_pressure_grid_lanes_match_their_single_runs(mixed):
+    """On rounds where one lane needs eviction the pass runs for every
+    lane; for a lane without need it evicts nothing, so each lane's
+    records stay bit-identical to its own single-lane run."""
+    trace, grid, _, singles = mixed
+    rec = grid.records
+    for k, (res, want, _) in enumerate(singles):
+        got = {
+            r.request_id: (rec["first"][k, j], rec["finish"][k, j],
+                           int(rec["out"][k, j]), int(rec["pre"][k, j]),
+                           bool(rec["trunc"][k, j]), bool(rec["rej"][k, j]))
+            for j, r in enumerate(trace)
+        }
+        assert got == want, k
+        assert int(grid.preemptions[k]) == res.preemptions
+    assert grid.preemptions[0] > 0
+    assert grid.preemptions[1] == 0
+
+
+def test_mixed_pressure_grid_runs_the_pass_on_need_rounds_only(mixed):
+    _, _, stats, singles = mixed
+    assert 0 < stats["evict_runs"] == stats["evict_need"] < stats["rounds"]
+    pressed_lane, calm_lane = (s for _, _, s in singles)
+    assert stats["evict_runs"] >= pressed_lane["evict_runs"] > 0
+    assert calm_lane["evict_runs"] == 0
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["single", "grid"])
+def test_eviction_pass_stays_a_branch(grid):
+    """The eviction pass sits under a ``cond`` in both modes: on a grid
+    its predicate is the lane maximum, so vmap keeps the branch instead of
+    batching it into a select that runs the pass every round."""
+    pools = (jax_engine._PoolSpec("short", 2048, 8, 2048, 2),
+             jax_engine._PoolSpec("long", 8192, 8, 2048, 2))
+    spec = jax_engine._SimSpec(pools=pools, w=2**-10, h=2**-13,
+                               prefill_chunk=512, win_size=16)
+    n, g = 64, 2
+    with jax.enable_x64():
+        fn = jax_engine._runner(spec, n, True, grid)
+        jaxpr = jax.make_jaxpr(fn)(
+            *jax_engine._abstract_inputs(spec, n, grid, g)).jaxpr
+
+    def conds(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "cond":
+                yield str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from conds(sub)
+
+    assert any(re.search(r"(^|/)evict$", name) for name in conds(jaxpr))
 
 
 def test_grid_counts_the_waves_the_device_executed(grids):
