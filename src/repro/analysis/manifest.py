@@ -118,17 +118,13 @@ DEFAULT_MANIFEST: dict = {
                 "and pressure ratios in float32 for vmappable lane axes; "
                 "decisions are threshold comparisons, bounded by the "
                 "gain-grid parity tests",
-                "_ctrl_params": "controller gain pack mirrors window_step's "
-                "float32 lanes",
-                "run_fleet_grid": "gain-grid rows feed the float32 "
-                "controller mirror",
+                "_ctrl_params": "the one table of controller lane fields "
+                "(both entry points' gain rows and the AOT abstract inputs "
+                "read it) mirrors window_step's float32 lanes",
                 "precompute_budget_trajectory": "EMA calibration state is "
                 "float32 by the CalibState contract (core/calibration.py); "
                 "the output is int32 budgets, never event-time math — "
                 "cold-start parity tests bound it",
-                "_abstract_inputs": "abstract avals for AOT lowering mirror "
-                "window_step's float32 controller-gain lanes; no runtime "
-                "values flow through them",
             }
         },
         "const_attrs": ["w_base", "h_per_seq"],

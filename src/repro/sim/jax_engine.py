@@ -77,20 +77,28 @@ rejection is staged as a reject *timestamp* (``rejt``, +inf = not
 rejected), so the boolean ``rej`` column and the reject first/finish
 times are folded in once after the loop rather than scattered inside it.
 
+The program simulates; the host summarises. The executable returns the
+folded records, per-pool counters, window snapshots, final thresholds
+and loop counters; every per-lane number (completions, routed counts,
+makespan, latency means and percentiles) is computed on the host from
+the records by ``_lane_summaries``, for both entry points. So the
+program holds no sort.
+
 The executables themselves are compiled ahead of time and cached
 (:func:`aot_compile` / ``_aot``): ``.lower().compile()`` under
-``enable_x64`` keyed by the static ``(spec, n, grid, g)`` shape, with
-wall-clock lower/compile times recorded in ``_COMPILE_STATS`` so the
-benchmark's ``jax_compile`` row measures compilation alone. The hot
-decode-advance pass is :func:`repro.kernels.sim_decode.decode_advance_jnp`,
-the same pass on every backend.
+``enable_x64``, one executable per static ``(spec, n, grid, g)`` shape,
+with wall-clock lower/compile times recorded in ``_COMPILE_STATS`` so
+``benchmarks/sim_throughput.py``'s ``jax_compile`` row measures
+compilation alone. The hot decode-advance pass is
+:func:`repro.kernels.sim_decode.decode_advance_jnp`, the same pass on
+every backend.
 
 Instrumentation
 ---------------
 Always on. Inside the executable, each round phase runs under a
 ``jax.named_scope`` (``_make_core``), and the carry counts admission
-waves, record-write trips, eviction passes, eviction need and live slot
-rows as the device executed them. On the host, each entry call is the span
+waves, record-write trips, eviction passes and live slot rows as the
+device executed them. On the host, each entry call is the span
 ``repro.sim.run`` with children for routing precompute, compilation,
 staging, the device run and result assembly: ``TraceAnnotation`` events
 on a device profile's clock, whose seconds also land in
@@ -217,14 +225,28 @@ class _SimSpec:
     win_size: int  # monitoring window in dispatched requests; 0 = off
 
 
-def _pool_spec(name: str, cfg: PoolConfig, max_inst: int) -> _PoolSpec:
+def _pool_spec(name: str, cfg: PoolConfig, max_inst: int) -> tuple:
+    """A pool's spec row from its config (the block budget a fresh pool
+    of that config gets)."""
     total = min(TOTAL_KV_BLOCKS, cfg.n_seq * _blocks_for(cfg.c_max))
-    return _PoolSpec(
-        name=name,
-        c_max=int(cfg.c_max),
-        n_seq=int(cfg.n_seq),
-        total_blocks=int(total),
-        max_inst=int(max_inst),
+    return name, cfg.c_max, cfg.n_seq, total, max_inst
+
+
+def _sim_spec(rows, timing: TimingModel, win_size: int) -> _SimSpec:
+    """The static spec of one fleet, for both entry points.
+
+    ``rows`` are ``(name, c_max, n_seq, total_blocks, max_inst)`` per
+    pool in budget order; ``win_size`` is the monitoring window in
+    dispatched requests (0 = off)."""
+    return _SimSpec(
+        pools=tuple(
+            _PoolSpec(str(name), *(int(v) for v in rest))
+            for name, *rest in rows
+        ),
+        w=float(timing.w_base),
+        h=float(timing.h_per_seq),
+        prefill_chunk=int(timing.prefill_chunk),
+        win_size=int(win_size),
     )
 
 
@@ -316,7 +338,6 @@ def _init_counters() -> dict:
         "adm_waves": z,
         "rec_trips": z,
         "evict_runs": z,
-        "evict_need": z,
         "live_slot_rounds": jnp.asarray(0, jnp.int64),
         "live_peak": z,
     }
@@ -360,17 +381,48 @@ def _unpack_records(rec: dict, n: int) -> dict:
     }
 
 
+def _lane_summaries(rec: dict, arr: np.ndarray, P: int) -> dict:
+    """Per-lane summaries of one run, on the host, from its records.
+
+    ``rec`` holds unpacked columns (``_unpack_records``), ``(n,)`` for a
+    single lane or ``(g, n)`` for a grid; ``arr`` is the ``(n,)`` arrival
+    column. A row is completed unless rejected; TTFT covers completed
+    rows, TPOT those of more than one token, over the full run with
+    NumPy's linear percentiles (NaN for a lane with no such row)."""
+    done = ~rec["rej"]
+    first, finish, out = rec["first"], rec["finish"], rec["out"]
+    ttft = np.where(done, first - arr, np.nan)
+    tpot = np.where(
+        done & (out > 1), (finish - first) / np.maximum(out - 1, 1), np.nan
+    )
+    t_end = finish.max(axis=-1)
+    with warnings.catch_warnings():
+        # all-NaN lanes read NaN; NumPy warns about them
+        warnings.simplefilter("ignore", RuntimeWarning)
+        ttft_p50, ttft_p99 = np.nanpercentile(ttft, (50, 99), axis=-1)
+        return {
+            "completed": done.sum(axis=-1),
+            "rejected": rec["rej"].sum(axis=-1),
+            "truncated": rec["trunc"].sum(axis=-1),
+            "routed": np.stack(
+                [np.sum(rec["pool"] == p, axis=-1) for p in range(P)], axis=-1
+            ),
+            "t_end": t_end,
+            "makespan": t_end - arr.min(),
+            "ttft_mean": np.nanmean(ttft, axis=-1),
+            "ttft_p50": ttft_p50,
+            "ttft_p99": ttft_p99,
+            "tpot_mean": np.nanmean(tpot, axis=-1),
+            "tpot_p99": np.nanpercentile(tpot, 99, axis=-1),
+        }
+
+
 # ---------------------------------------------------------------------------
 # The compiled core
 # ---------------------------------------------------------------------------
 
 
-def _make_core(
-    spec: _SimSpec,
-    n: int,
-    return_records: bool,
-    lanes: Optional[str] = None,
-):
+def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
     """Build the single-lane simulation function for one (spec, n).
 
     Returned function signature: ``core(trace, lane, rec0) -> dict``
@@ -378,6 +430,10 @@ def _make_core(
     per-lane (vmappable) parameters, and ``rec0`` the donated record
     buffers (see ``_fresh_records``). Must be traced/executed inside an
     ``enable_x64()`` context — event times are float64 accumulations.
+    The output holds the folded records (``rec``, aliasing the donated
+    buffers), per-pool counters, final thresholds, controller moves,
+    window snapshots and loop counters, and no per-lane summary (the
+    host computes those from the records: ``_lane_summaries``).
 
     The pool state is a single stacked ``(P, I, S)`` pytree (see
     ``_init_pools``) so one traced round body covers every pool — on the
@@ -407,8 +463,8 @@ def _make_core(
     (``ctr`` in the carry, returned in ``out``) count what the device
     executed: under ``vmap`` a batched ``while`` runs until its last
     lane is done, so the admission and record-write trip counts and the
-    eviction need are ``lax.pmax``-ed over ``lanes`` (the identity for a
-    single lane).
+    eviction gate's predicate are ``lax.pmax``-ed over ``lanes`` (the
+    identity for a single lane).
     """
     P = len(spec.pools)
     win = spec.win_size
@@ -467,7 +523,7 @@ def _make_core(
         return x if lanes is None else lax.pmax(x, lanes)
 
     def core(trace, lane, rec0):
-        _count_trace(("sim_core", P, n, bool(return_records)))
+        _count_trace(("sim_core", P, n))
         arr_t = trace["arr"]
         inp_t = trace["inp"]
         out_t = trace["outp"]
@@ -1041,14 +1097,12 @@ def _make_core(
                 # Loop counters: scalars beside the state update (the live
                 # slot sum is one (P, I) reduce of the post-admission nact,
                 # and the peak is a max beside it).
-                ran = need.astype(i32)
                 live = jnp.sum(nact, dtype=i32)
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
                     "rec_trips": ctr["rec_trips"] + trips,
-                    "evict_runs": ctr["evict_runs"] + ran,
-                    "evict_need": ctr["evict_need"] + ran,
+                    "evict_runs": ctr["evict_runs"] + need.astype(i32),
                     "live_slot_rounds": ctr["live_slot_rounds"]
                     + live.astype(jnp.int64),
                     "live_peak": jnp.maximum(ctr["live_peak"], live),
@@ -1173,81 +1227,37 @@ def _make_core(
                 jnp.where(arej, rejt, arr_p)[:, None],
                 c["rec"]["recf"],
             )
-            rec_full = {
+        return {
+            "rec": {
                 "recf": recf_full,
                 "reci": c["rec"]["reci"],
                 "pool": c["pool"],
                 "rejt": rejt,
                 "rej": rejm,
-            }
-            finish = recf_full[:n, 1]
-            trunc = rec_full["reci"][:n, 2]
-            pool_c = c["pool"][:n]
-
-            compm = ~rejm[:n]
-            metrics = {
-                "completed": jnp.sum(compm),
-                "rejected": jnp.sum(rejm[:n]),
-                "truncated": jnp.sum(trunc),
-                "routed": jnp.stack([jnp.sum(pool_c == p) for p in range(P)]),
-                "t_end": jnp.max(finish),
-                "makespan": jnp.max(finish) - jnp.min(arr_t),
-            }
-            if not gate:
-                # Latency summaries for FleetGridResult. A single lane's
-                # host summarizes its own records instead, and skipping
-                # the percentiles' float64 sorts there matters: at 60,000
-                # rows they added about a minute to a TPU v5e's compile.
-                first = recf_full[:n, 0]
-                out_tok = rec_full["reci"][:n, 0]
-                ttft = jnp.where(compm, first - arr_t, jnp.nan)
-                tpot = jnp.where(
-                    compm & (out_tok > 1),
-                    (finish - first) / jnp.maximum(out_tok - 1, 1),
-                    jnp.nan,
-                )
-                metrics.update(
-                    ttft_mean=jnp.nanmean(ttft),
-                    ttft_p50=jnp.nanpercentile(ttft, 50),
-                    ttft_p99=jnp.nanpercentile(ttft, 99),
-                    tpot_mean=jnp.nanmean(tpot),
-                    tpot_p99=jnp.nanpercentile(tpot, 99),
-                )
-            out = {
-                "metrics": metrics,
-                "preempt": c["pools"]["npre"],
-                "reject": c["pools"]["nrej"],
-                "truncate": c["pools"]["ntr"],
-                "th": c["th"],
-                "moves": c["moves"],
-                "nwin": c["wi"],
-                "win": c["win"],
-                "iters": c["iters"],
-                **c["ctr"],
-            }
-            if return_records:
-                # Full (n + 1,) leaves so every output can alias its donated
-                # input buffer; callers slice off the scratch row.
-                out["rec"] = rec_full
-        return out
+            },
+            "preempt": c["pools"]["npre"],
+            "reject": c["pools"]["nrej"],
+            "truncate": c["pools"]["ntr"],
+            "th": c["th"],
+            "moves": c["moves"],
+            "nwin": c["wi"],
+            "win": c["win"],
+            "iters": c["iters"],
+            **c["ctr"],
+        }
 
     return core
 
 
 @functools.lru_cache(maxsize=None)
-def _runner(
-    spec: _SimSpec,
-    n: int,
-    return_records: bool,
-    grid: bool,
-):
-    """Cached jitted simulation, specialized per (spec, n, outputs, vmap).
+def _runner(spec: _SimSpec, n: int, grid: bool):
+    """Cached jitted simulation, specialized per (spec, n, vmap).
 
     The third argument (record buffers) is donated — XLA writes the
     scatters into the caller's buffers in place."""
     if not grid:
-        return jax.jit(_make_core(spec, n, return_records), donate_argnums=(2,))
-    core = _make_core(spec, n, return_records, lanes="lanes")
+        return jax.jit(_make_core(spec, n), donate_argnums=(2,))
+    core = _make_core(spec, n, lanes="lanes")
     fn = jax.vmap(core, in_axes=(None, 0, 0), axis_name="lanes")
     return jax.jit(fn, donate_argnums=(2,))
 
@@ -1256,7 +1266,7 @@ def _runner(
 # AOT executable cache + probes
 # ---------------------------------------------------------------------------
 
-#: {(spec, n, return_records, grid, g): {"lower_s", "compile_s"}}
+#: {(spec, n, grid, g): {"lower_s", "compile_s"}}
 _COMPILE_STATS: dict = {}
 
 #: Counters and host spans of the most recent compiled run (see
@@ -1310,7 +1320,7 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
         **{
             k: int(np.max(out[k]))
             for k in ("rounds", "adm_waves", "rec_trips", "evict_runs",
-                      "evict_need", "live_peak")
+                      "live_peak")
         },
         rounds_total=int(np.sum(out["rounds"])),
         live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
@@ -1353,14 +1363,7 @@ def _abstract_inputs(spec: _SimSpec, n: int, grid: bool, g: int):
     lane = {
         "th": L((P - 1,), np.int32),
         "ninst": L((P,), np.int32),
-        "ctrl": {
-            "enabled": L((), np.int32),
-            "b_min": L((), np.int32),
-            "step": L((), np.int32),
-            "factor": L((), np.float32),
-            "err_hi": L((), np.float32),
-            "over_hi": L((), np.float32),
-        },
+        "ctrl": {k: L((), v.dtype) for k, v in _ctrl_params(None).items()},
     }
     rec = {
         name: L((n + 1,) if w == 1 else (n + 1, w), dt)
@@ -1370,13 +1373,7 @@ def _abstract_inputs(spec: _SimSpec, n: int, grid: bool, g: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _aot(
-    spec: _SimSpec,
-    n: int,
-    return_records: bool,
-    grid: bool,
-    g: int,
-):
+def _aot(spec: _SimSpec, n: int, grid: bool, g: int):
     """AOT-compiled executable for one static shape key.
 
     ``.lower().compile()`` runs here exactly once per key, under the
@@ -1384,22 +1381,15 @@ def _aot(
     wall-clock times land in ``_COMPILE_STATS`` so the benchmark's
     ``jax_compile`` row can report compilation alone (no run attached).
     """
-    with jax.enable_x64(), warnings.catch_warnings():
-        if not return_records:
-            # Without record outputs the donated buffers have no output
-            # to alias into — donation still lets XLA recycle them as
-            # in-loop scratch, so the "not usable" note is expected.
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable"
-            )
-        fn = _runner(spec, n, return_records, grid)
+    with jax.enable_x64():
+        fn = _runner(spec, n, grid)
         targs, lane, rec = _abstract_inputs(spec, n, grid, g)
         stats = {}
         with _span("repro.sim.lower", stats, "lower_s", n=n, g=g):
             lowered = fn.lower(targs, lane, rec)
         with _span("repro.sim.backend_compile", stats, "compile_s", n=n, g=g):
             compiled = lowered.compile()
-    _COMPILE_STATS[(spec, n, return_records, grid, g)] = stats
+    _COMPILE_STATS[(spec, n, grid, g)] = stats
     return compiled
 
 
@@ -1420,10 +1410,10 @@ def last_run_stats() -> dict:
     * ``adm_waves``: trips of the admission fixpoint;
     * ``rec_trips``: record-write trips (each writes up to 32 completing
       slots' records; a round with no completion runs none);
-    * ``evict_runs``: rounds in which the gate ran the eviction pass
-      (on a grid, for every lane at once when any lane needed it);
-    * ``evict_need``: rounds in which some instance's growth demand
-      exceeded its free blocks (in any lane, on a grid);
+    * ``evict_runs``: rounds in which the gate ran the eviction pass,
+      that is rounds in which some instance's growth demand exceeded its
+      free blocks (on a grid, in any lane: the pass then runs for every
+      lane at once);
     * ``live_slot_rounds``: live decode slots after admission, summed over
       each lane's own rounds and over lanes, so
       ``live_slot_rounds / (g * rounds * slot_rows)`` is the share of the
@@ -1441,8 +1431,9 @@ def last_run_stats() -> dict:
     executable: an ``_aot`` miss's lower and compile, microseconds on a
     hit), ``repro.sim.stage`` (host input arrays), ``repro.sim.device_run``
     (the executable call until its outputs are on the host) and
-    ``repro.sim.results`` (record unpack, back-fill, history, telemetry,
-    summaries). ``run - device_run`` is the host's own time in the call.
+    ``repro.sim.results`` (record unpack, per-lane summaries, back-fill,
+    history, telemetry). ``run - device_run`` is the host's own time in
+    the call.
     """
     return {**_LAST_RUN, "spans": dict(_LAST_RUN.get("spans", {}))}
 
@@ -1450,18 +1441,13 @@ def last_run_stats() -> dict:
 def compile_stats() -> list[dict]:
     """Every AOT compilation this process paid, with readable keys.
 
-    One dict per ``_aot`` cache entry: ``n``, ``return_records``,
-    ``grid``, ``g`` plus the measured ``lower_s`` /
-    ``compile_s`` walls. Benchmarks use this to report grid-executable
-    compile time without re-deriving the cache key."""
+    One dict per ``_aot`` cache entry, that is one per executable (a
+    grid's record mode does not change its program): ``n``, ``grid``,
+    ``g`` plus the measured ``lower_s`` / ``compile_s`` walls.
+    Benchmarks use this to report grid-executable compile time without
+    re-deriving the cache key."""
     return [
-        {
-            "n": k[1],
-            "return_records": k[2],
-            "grid": k[3],
-            "g": k[4],
-            **v,
-        }
+        {"n": k[1], "grid": k[2], "g": k[3], **v}
         for k, v in _COMPILE_STATS.items()
     ]
 
@@ -1531,10 +1517,11 @@ def aot_compile(fleet, trace) -> dict:
     plus ``cached`` (True when the executable already existed, i.e. the
     times are from the original compilation). The subsequent
     ``run_fleet`` call for the same shape hits the cache and pays no
-    compilation."""
+    compilation: the program is the one every single-lane call runs,
+    which returns records and leaves summaries to the host."""
     cols = _as_columns(trace)
     spec, _, _ = _fleet_spec(fleet, cols)
-    key = (spec, len(cols), True, False, 0)
+    key = (spec, len(cols), False, 0)
     cached = key in _COMPILE_STATS
     with jax.enable_x64():
         _aot(*key)
@@ -1642,25 +1629,32 @@ def _trace_arrays(cols: TraceColumns, budgets: Optional[np.ndarray]):
     }
 
 
-def _ctrl_params(controller, enabled: bool):
-    """Controller gains as a traced scalar dict (a vmappable lane axis)."""
-    if controller is None:
-        return {
-            "enabled": np.int32(0),
-            "b_min": np.int32(512),
-            "step": np.int32(DEFAULT_INCREASE_STEP),
-            "factor": np.float32(DEFAULT_DECREASE_FACTOR),
-            "err_hi": np.float32(DEFAULT_ERROR_RATE_HI),
-            "over_hi": np.float32(DEFAULT_OVERLOAD_RATIO_HI),
-        }
-    return {
-        "enabled": np.int32(1 if enabled else 0),
-        "b_min": np.int32(controller.b_min),
-        "step": np.int32(controller.increase_step),
-        "factor": np.float32(controller.decrease_factor),
-        "err_hi": np.float32(controller.error_rate_hi),
-        "over_hi": np.float32(controller.overload_ratio_hi),
-    }
+def _ctrl_params(gains: Optional[dict]) -> dict:
+    """One lane's controller parameters, the in-step AIMD mirror's
+    vmappable lane fields.
+
+    ``gains`` maps :class:`~repro.core.adaptive.AdaptiveController`'s
+    attribute names to values (a missing one takes the controller's
+    default), or is ``None`` for an uncontrolled lane. Both entry points
+    and the abstract inputs read the fields from here."""
+    fields = (
+        ("b_min", "b_min", np.int32, 512),
+        ("step", "increase_step", np.int32, DEFAULT_INCREASE_STEP),
+        ("factor", "decrease_factor", np.float32, DEFAULT_DECREASE_FACTOR),
+        ("err_hi", "error_rate_hi", np.float32, DEFAULT_ERROR_RATE_HI),
+        ("over_hi", "overload_ratio_hi", np.float32,
+         DEFAULT_OVERLOAD_RATIO_HI),
+    )
+    row = {"enabled": np.int32(gains is not None)}
+    for key, name, dtype, default in fields:
+        row[key] = dtype((gains or {}).get(name, default))
+    return row
+
+
+def _epoch_cap(epoch: int, control_window: int, controlled: bool) -> int:
+    """The widest routing epoch: a controlled fleet folds its calibration
+    at least once a control window."""
+    return max(1, min(epoch, control_window)) if controlled else epoch
 
 
 def _as_columns(trace) -> TraceColumns:
@@ -1675,25 +1669,35 @@ def _fleet_spec(fleet, cols: TraceColumns):
     """Build the static spec for a live FleetSim (shared with the probes)."""
     ordered = sorted(fleet._pool_index, key=fleet._pool_index.get)
     shells = [fleet.pools[name] for name in ordered]
-    spec = _SimSpec(
-        # Capacities come from the live shells (not recomputed from the
-        # config) so post-construction total_blocks overrides are honored.
-        pools=tuple(
-            _PoolSpec(
-                name=name,
-                c_max=int(s.config.c_max),
-                n_seq=int(s.config.n_seq),
-                total_blocks=int(s.total_blocks),
-                max_inst=int(s.num_instances),
-            )
-            for name, s in zip(ordered, shells)
-        ),
-        w=float(fleet.timing.w_base),
-        h=float(fleet.timing.h_per_seq),
-        prefill_chunk=int(fleet.timing.prefill_chunk),
-        win_size=int(fleet._win_size),
-    )
-    return spec, ordered, shells
+    # Capacities come from the live shells (not recomputed from the
+    # config) so post-construction total_blocks overrides are honored.
+    rows = [
+        (name, s.config.c_max, s.config.n_seq, s.total_blocks,
+         s.num_instances)
+        for name, s in zip(ordered, shells)
+    ]
+    return _sim_spec(rows, fleet.timing, fleet._win_size), ordered, shells
+
+
+def _run_program(spec: _SimSpec, cols: TraceColumns, budgets, lane: dict,
+                 g: Optional[int] = None) -> dict:
+    """Get the executable, stage the inputs and run it; host outputs.
+
+    ``g`` is a grid's lane count (``None``: a single lane). Runs under
+    the entry's ``repro.sim.compile``, ``repro.sim.stage`` and
+    ``repro.sim.device_run`` spans, then notes the loop counters."""
+    spans = _LAST_RUN["spans"]
+    n = len(cols)
+    lanes = 1 if g is None else g
+    with jax.enable_x64():
+        with _span("repro.sim.compile", spans, n=n, g=lanes):
+            exe = _aot(spec, n, g is not None, g or 0)
+        with _span("repro.sim.stage", spans, n=n, g=lanes):
+            args = (_trace_arrays(cols, budgets), lane, _fresh_records(n, g))
+        with _span("repro.sim.device_run", spans, n=n, g=lanes):
+            out = jax.tree_util.tree_map(np.asarray, exe(*args))
+    _note_counters(out, spec, lanes)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1723,12 +1727,11 @@ def run_fleet_jax(fleet, trace):
     P = len(spec.pools)
 
     router = fleet.router
+    controller = fleet.controller
     budgets = None
     if router is not None and n:
-        epoch_cap = (
-            fleet.epoch
-            if fleet.controller is None
-            else max(1, min(fleet.epoch, fleet.control_window))
+        epoch_cap = _epoch_cap(
+            fleet.epoch, fleet.control_window, controller is not None
         )
         with _span("repro.sim.route_precompute", spans, n=n, g=1):
             budgets, final_state = precompute_budget_trajectory(
@@ -1760,27 +1763,18 @@ def run_fleet_jax(fleet, trace):
             telemetry=fleet.telemetry, slo=fleet.slo,
         )
 
-    with jax.enable_x64():
-        with _span("repro.sim.compile", spans, n=n, g=1):
-            exe = _aot(spec, n, True, False, 0)
-        with _span("repro.sim.stage", spans, n=n, g=1):
-            lane = {
-                "th": np.asarray(th0, np.int32),
-                "ninst": np.asarray(
-                    [fleet.pools[name].num_instances for name in ordered],
-                    np.int32,
-                ),
-                "ctrl": _ctrl_params(fleet.controller, enabled=True),
-            }
-            args = (_trace_arrays(cols, budgets), lane, _fresh_records(n))
-        with _span("repro.sim.device_run", spans, n=n, g=1):
-            out = jax.tree_util.tree_map(np.asarray, exe(*args))
+    lane = {
+        "th": np.asarray(th0, np.int32),
+        "ninst": np.asarray([s.num_instances for s in shells], np.int32),
+        "ctrl": _ctrl_params(None if controller is None else vars(controller)),
+    }
+    out = _run_program(spec, cols, budgets, lane)
 
     with _span("repro.sim.results", spans, n=n, g=1):
-        _note_counters(out, spec, 1)
         rec = _unpack_records(out["rec"], n)
         ids = np.asarray(cols.request_id, np.int64)
         arr = np.asarray(cols.arrival_time, np.float64)
+        lane_sum = _lane_summaries(rec, arr, P)
         fleet_cols = {
             "request_id": ids,
             "arrival": arr,
@@ -1802,14 +1796,14 @@ def run_fleet_jax(fleet, trace):
             shell.rejection_count = int(out["reject"][idx])
             shell.truncation_count = int(out["truncate"][idx])
             if router is not None:
-                router.routed[name] += int(out["metrics"]["routed"][idx])
+                router.routed[name] += int(lane_sum["routed"][idx])
 
         final_th = [int(b) for b in out["th"][: P - 1]]
-        if router is not None and fleet.controller is not None:
+        if router is not None and controller is not None:
             router.pools.set_thresholds(final_th)
-            _synthesize_history(fleet.controller, out, th0)
+            _synthesize_history(controller, out, th0)
 
-        t_end = float(out["metrics"]["t_end"])
+        t_end = float(lane_sum["t_end"])
         if fleet.telemetry is not None:
             _replay_telemetry(fleet, ordered, shells, spec, out, n, t_end, final_th)
 
@@ -1908,10 +1902,11 @@ def _replay_telemetry(fleet, ordered, shells, spec, out, n, t_end, final_th):
 class FleetGridResult:
     """Columnar results of one vmapped fleet sweep (G grid lanes).
 
-    Per-lane reductions are computed on device over the *full* run (no
-    warm-up discard — grid metrics are for relative comparisons across
-    lanes; use a single-lane ``FleetSim`` run for paper-grade numbers).
-    Percentiles are linear-interpolation (``jnp.nanpercentile``), not the
+    Per-lane reductions are computed on the host from the records the
+    program returns (``_lane_summaries``), over the *full* run (no warm-up
+    discard — grid metrics are for relative comparisons across lanes; use
+    a single-lane ``FleetSim`` run for paper-grade numbers). Percentiles
+    are linear-interpolation (``np.nanpercentile``'s default), not the
     nearest-rank convention of :func:`repro.sim.metrics.summarize`.
     """
 
@@ -1989,6 +1984,10 @@ def run_fleet_grid(
     depends only on the observation stream, not on routing — so every lane
     shares the same budget array and the sweep stays exact w.r.t. the
     single-lane jax backend (asserted by the grid-parity test).
+
+    ``return_records`` keeps the per-request records on the result; the
+    program is the same either way (it always returns them, and the lane
+    summaries come from them).
     """
     spans = _LAST_RUN["spans"]
     cols = _as_columns(trace)
@@ -2023,63 +2022,29 @@ def run_fleet_grid(
     th_arr = np.asarray(thresholds, np.int32).reshape(g, P - 1)
     inst_arr = np.asarray(instances, np.int32).reshape(g, P)
     any_ctrl = any(gn is not None for gn in gains)
-    ctrl_rows = []
-    for gn in gains:
-        row = {
-            "enabled": np.int32(0 if gn is None else 1),
-            "b_min": np.int32((gn or {}).get("b_min", 512)),
-            "step": np.int32(
-                (gn or {}).get("increase_step", DEFAULT_INCREASE_STEP)
-            ),
-            "factor": np.float32(
-                (gn or {}).get("decrease_factor", DEFAULT_DECREASE_FACTOR)
-            ),
-            "err_hi": np.float32(
-                (gn or {}).get("error_rate_hi", DEFAULT_ERROR_RATE_HI)
-            ),
-            "over_hi": np.float32(
-                (gn or {}).get("overload_ratio_hi", DEFAULT_OVERLOAD_RATIO_HI)
-            ),
-        }
-        ctrl_rows.append(row)
-    ctrl = {
-        k: np.stack([r[k] for r in ctrl_rows]) for k in ctrl_rows[0]
-    }
-
-    spec = _SimSpec(
-        pools=tuple(
-            _pool_spec(name, cfg, int(inst_arr[:, j].max()))
-            for j, (name, cfg) in enumerate(zip(names, configs))
-        ),
-        w=float(timing.w_base),
-        h=float(timing.h_per_seq),
-        prefill_chunk=int(timing.prefill_chunk),
-        win_size=int(control_window) if any_ctrl else 0,
-    )
+    ctrl_rows = [_ctrl_params(gn) for gn in gains]
+    ctrl = {k: np.stack([r[k] for r in ctrl_rows]) for k in ctrl_rows[0]}
+    rows = [
+        _pool_spec(name, cfg, inst_arr[:, j].max())
+        for j, (name, cfg) in enumerate(zip(names, configs))
+    ]
+    spec = _sim_spec(rows, timing, control_window if any_ctrl else 0)
 
     budgets = None
     if P > 1:
         cal = calibrator or EmaCalibrator()
-        epoch_cap = (
-            max(1, min(epoch, control_window)) if any_ctrl else epoch
-        )
+        epoch_cap = _epoch_cap(epoch, control_window, any_ctrl)
         with _span("repro.sim.route_precompute", spans, n=n, g=g):
             budgets, _ = precompute_budget_trajectory(
                 cols, cal, epoch_cap=epoch_cap
             )
 
-    with jax.enable_x64():
-        with _span("repro.sim.compile", spans, n=n, g=g):
-            exe = _aot(spec, n, return_records, True, g)
-        with _span("repro.sim.stage", spans, n=n, g=g):
-            lane = {"th": th_arr, "ninst": inst_arr, "ctrl": ctrl}
-            args = (_trace_arrays(cols, budgets), lane, _fresh_records(n, g))
-        with _span("repro.sim.device_run", spans, n=n, g=g):
-            out = jax.tree_util.tree_map(np.asarray, exe(*args))
+    lane = {"th": th_arr, "ninst": inst_arr, "ctrl": ctrl}
+    out = _run_program(spec, cols, budgets, lane, g)
 
     with _span("repro.sim.results", spans, n=n, g=g):
-        _note_counters(out, spec, g)
-        m = out["metrics"]
+        rec = _unpack_records(out["rec"], n)
+        m = _lane_summaries(rec, np.asarray(cols.arrival_time, np.float64), P)
         return FleetGridResult(
             pool_names=names,
             thresholds=th_arr,
@@ -2098,7 +2063,5 @@ def run_fleet_grid(
             final_thresholds=out["th"].reshape(g, P - 1)[:, : P - 1],
             controller_moves=out["moves"].astype(np.int64),
             live_peak=out["live_peak"].astype(np.int64),
-            records=(
-                _unpack_records(out["rec"], n) if "rec" in out else None
-            ),
+            records=rec if return_records else None,
         )

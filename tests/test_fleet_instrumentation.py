@@ -1,7 +1,7 @@
 """Loop counters, phase scopes and host spans of the compiled fleet tier.
 
 ``jax_engine`` counts what the device executed inside the event loop
-(admission waves, eviction passes and need, live slot rows) and times
+(admission waves, eviction passes, live slot rows) and times
 each entry call's host phases as ``repro.sim.*`` spans. These tests pin
 the counters' invariants on tiny traces, in both modes, and check that
 the phase scopes reach the lowered program's op metadata. Record parity
@@ -95,7 +95,7 @@ def grids():
 
 
 COUNTERS = ("iters", "rounds", "adm_waves", "rec_trips", "evict_runs",
-            "evict_need", "live_slot_rounds", "slot_rows", "rounds_total")
+            "live_slot_rounds", "slot_rows", "rounds_total")
 
 
 def test_counters_exist_in_both_modes(calm, grids):
@@ -112,15 +112,14 @@ def test_counters_exist_in_both_modes(calm, grids):
 def test_single_lane_without_pressure_never_evicts(calm):
     res, stats = calm
     assert res.preemptions == 0
-    assert stats["evict_runs"] == stats["evict_need"] == 0
+    assert stats["evict_runs"] == 0
     assert stats["adm_waves"] > 0
 
 
 def test_single_lane_under_pressure_runs_the_gated_pass(pressed):
     res, stats = pressed
     assert res.preemptions > 0
-    assert stats["evict_runs"] >= stats["evict_need"] > 0
-    assert stats["evict_runs"] < stats["rounds"]
+    assert 0 < stats["evict_runs"] < stats["rounds"]
 
 
 def test_grid_gates_the_pass(grids):
@@ -128,7 +127,7 @@ def test_grid_gates_the_pass(grids):
     gate never runs the pass, whether the lanes run together or alone."""
     (_, both), alone = grids
     for stats in [both] + [s for _, s in alone]:
-        assert stats["evict_runs"] == stats["evict_need"] == 0
+        assert stats["evict_runs"] == 0
 
 
 def _records(sim):
@@ -187,7 +186,7 @@ def test_mixed_pressure_grid_lanes_match_their_single_runs(mixed):
 
 def test_mixed_pressure_grid_runs_the_pass_on_need_rounds_only(mixed):
     _, _, stats, singles = mixed
-    assert 0 < stats["evict_runs"] == stats["evict_need"] < stats["rounds"]
+    assert 0 < stats["evict_runs"] < stats["rounds"]
     pressed_lane, calm_lane = (s for _, _, s in singles)
     assert stats["evict_runs"] >= pressed_lane["evict_runs"] > 0
     assert calm_lane["evict_runs"] == 0
@@ -204,7 +203,7 @@ def test_eviction_pass_stays_a_branch(grid):
                                prefill_chunk=512, win_size=16)
     n, g = 64, 2
     with jax.enable_x64():
-        fn = jax_engine._runner(spec, n, True, grid)
+        fn = jax_engine._runner(spec, n, grid)
         jaxpr = jax.make_jaxpr(fn)(
             *jax_engine._abstract_inputs(spec, n, grid, g)).jaxpr
 
@@ -284,8 +283,7 @@ def test_compile_stats_keep_their_keys(grids):
     rows = jax_engine.compile_stats()
     assert rows
     for row in rows:
-        assert {"n", "return_records", "grid", "g", "lower_s",
-                "compile_s"} <= set(row)
+        assert {"n", "grid", "g", "lower_s", "compile_s"} <= set(row)
         assert row["lower_s"] > 0 and row["compile_s"] > 0
 
 
@@ -300,7 +298,7 @@ def test_lowered_runner_carries_every_scope(grid):
                                prefill_chunk=512, win_size=16)
     n, g = 64, 2
     with jax.enable_x64():
-        fn = jax_engine._runner(spec, n, True, grid)
+        fn = jax_engine._runner(spec, n, grid)
         text = fn.lower(*jax_engine._abstract_inputs(spec, n, grid, g)
                         ).as_text(debug_info=True)
     for scope in SCOPES:

@@ -135,8 +135,8 @@ def test_single_lane_program_sorts_nothing_at_the_cells_length():
     """Float64 sorts of tens of thousands of rows are slow to compile for
     the TPU (about a minute on a v5e at 60,000 requests), so the
     single-lane program, which the cell runs at tens of
-    thousands of requests, must lower without one (the grid's latency
-    percentiles keep theirs)."""
+    thousands of requests, must lower without one, and so must the grid
+    program (its latency percentiles are computed on the host)."""
     _, _, prog, pools, timing, _ = _inputs(3)
     fleet = FleetSim(pools, timing, backend="jax", spillover=False,
                      thresholds=[8192])
@@ -144,7 +144,7 @@ def test_single_lane_program_sorts_nothing_at_the_cells_length():
                        .read_text())["requests"])
     spec = jax_engine._fleet_spec(fleet, prog)[0]
     with jax.enable_x64():
-        for grid, g, sorts in ((False, 0, 0), (True, 2, 1)):
-            text = jax_engine._runner(spec, n, True, grid).lower(
+        for grid, g in ((False, 0), (True, 2)):
+            text = jax_engine._runner(spec, n, grid).lower(
                 *jax_engine._abstract_inputs(spec, n, grid, g)).as_text()
-            assert text.count("stablehlo.sort") == sorts, grid
+            assert text.count("stablehlo.sort") == 0, grid

@@ -76,7 +76,7 @@ def test_des_runner_compiles_for_v5e(one_chip, grid, g):
     n = 1000
     spec = _des_spec(n)
     with jax.enable_x64():
-        fn = jax_engine._runner(spec, n, True, grid)
+        fn = jax_engine._runner(spec, n, grid)
         args = _on(jax_engine._abstract_inputs(spec, n, grid, g), one_chip)
         compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()

@@ -1139,6 +1139,58 @@ class TestFleetGrid:
                 gains=[None, None, None],
             )
 
+    def test_grid_summaries_come_from_the_records(self):
+        """Every per-lane number of a grid is a summary of its records:
+        counts and makespan equal those recomputed from the records, and
+        the latency means and percentiles equal ``jnp.nanmean`` /
+        ``jnp.nanpercentile`` over them under x64, to float64 rounding."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.sim.jax_engine import run_fleet_grid
+
+        trace = poisson_trace(300, 220.0, 11, l_in=(16, 4000), l_out=(1, 200))
+        pools = {
+            "short": (PoolConfig("short", 2048, 8), 2),
+            "long": (PoolConfig("long", 8192, 8), 2),
+        }
+        # a boundary of 1 sends every request long (no prompt is over its
+        # C_max); 8191 sends most short, where long prompts are rejected
+        grid = run_fleet_grid(trace, pools, DYADIC,
+                              thresholds=[[1], [8191]], return_records=True)
+        rec = grid.records
+        arr = np.sort([r.arrival_time for r in trace])
+        assert grid.rejected[0] == 0 < grid.rejected[1]
+        for k in range(2):
+            rej = rec["rej"][k]
+            assert grid.completed[k] == np.sum(~rej)
+            assert grid.rejected[k] == np.sum(rej)
+            assert grid.truncated[k] == np.sum(rec["trunc"][k])
+            assert grid.routed[k].tolist() == [
+                int(np.sum(rec["pool"][k] == p)) for p in range(2)]
+            assert grid.makespan[k] == np.max(rec["finish"][k]) - arr[0]
+            with jax.enable_x64():
+                first = jnp.asarray(rec["first"][k])
+                finish = jnp.asarray(rec["finish"][k])
+                out = jnp.asarray(rec["out"][k])
+                done = jnp.asarray(~rej)
+                ttft = jnp.where(done, first - jnp.asarray(arr), jnp.nan)
+                tpot = jnp.where(done & (out > 1),
+                                 (finish - first) / jnp.maximum(out - 1, 1),
+                                 jnp.nan)
+                want = {
+                    "ttft_mean": jnp.nanmean(ttft),
+                    "ttft_p50": jnp.nanpercentile(ttft, 50),
+                    "ttft_p99": jnp.nanpercentile(ttft, 99),
+                    "tpot_mean": jnp.nanmean(tpot),
+                    "tpot_p99": jnp.nanpercentile(tpot, 99),
+                }
+                want = {f: float(v) for f, v in want.items()}
+            for field, value in want.items():
+                assert np.isfinite(value), field
+                assert getattr(grid, field)[k] == pytest.approx(
+                    value, rel=1e-12), (k, field)
+
 
 class TestKernelCaching:
     """Routing/observe kernel specializations are cached by ``(name, …)``
@@ -1193,7 +1245,7 @@ class TestDonatedBufferParity:
             assert tuples == base
 
     def test_interleaved_grid_record_modes(self, fixture):
-        from repro.sim.jax_engine import run_fleet_grid
+        from repro.sim.jax_engine import compile_stats, run_fleet_grid
 
         _, trace = fixture
         pools = {
@@ -1211,9 +1263,14 @@ class TestDonatedBufferParity:
                 return_records=return_records,
             )
 
+        rows0 = len(compile_stats())
         with_rec = grid(True)
         summary_only = grid(False)
         again = grid(True)
+        # both record modes ran one executable
+        new = [r for r in compile_stats()[rows0:]
+               if r["grid"] and (r["n"], r["g"]) == (len(trace), 2)]
+        assert len(new) == 1
         assert summary_only.records is None
         assert (with_rec.completed == summary_only.completed).all()
         assert (with_rec.completed == again.completed).all()
