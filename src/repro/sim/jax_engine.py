@@ -97,8 +97,9 @@ Instrumentation
 ---------------
 Always on. Inside the executable, each round phase runs under a
 ``jax.named_scope`` (``_make_core``), and the carry counts admission
-waves, record-write trips, eviction passes and live slot rows as the
-device executed them. On the host, each entry call is the span
+waves (and those that ran the gated reject write), record-write trips,
+eviction passes and live slot rows as the device executed them. On the
+host, each entry call is the span
 ``repro.sim.run`` with children for routing precompute, compilation,
 staging, the device run and result assembly: ``TraceAnnotation`` events
 on a device profile's clock, whose seconds also land in
@@ -336,6 +337,7 @@ def _init_counters() -> dict:
     return {
         "rounds": z,
         "adm_waves": z,
+        "rej_writes": z,
         "rec_trips": z,
         "evict_runs": z,
         "live_slot_rounds": jnp.asarray(0, jnp.int64),
@@ -449,7 +451,14 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
     skipped branch is bit-identical to the masked pass (``jsel = 0``
     evicts nothing), and so is the pass for a lane with no need on a
     round where another lane has some, so gating never changes
-    results. The mode also selects the outer-loop shape: nested
+    results. The admission wave's reject write (the ``rejt`` staging
+    scatter, one row per instance head) is gated the same way: it runs
+    under a ``cond`` only on waves where some head rejects, in any lane.
+    With the default block budget no head can reject at admission (a
+    prompt that outgrows it is rejected at submit), so only a fleet whose
+    budget a user set below one request's blocks runs it; a lane without
+    a reject writes only its scratch row. The mode also selects the
+    outer-loop shape: nested
     drain→sweep epochs for the single-lane path, drain + exactly one
     round per outer iteration for vmapped grids (a nested sweep loop
     would run to the max round count over lanes per epoch — a measured
@@ -463,7 +472,7 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
     (``ctr`` in the carry, returned in ``out``) count what the device
     executed: under ``vmap`` a batched ``while`` runs until its last
     lane is done, so the admission and record-write trip counts and the
-    eviction gate's predicate are ``lax.pmax``-ed over ``lanes`` (the
+    two gates' predicates are ``lax.pmax``-ed over ``lanes`` (the
     identity for a single lane).
     """
     P = len(spec.pools)
@@ -748,12 +757,12 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
                 return stash, hrid, hc, hinp, hpc, need, rejm, admm
 
             def adm_cond(val):
-                st_, _, _ = val
+                st_ = val[0]
                 *_, rejm, admm = adm_masks(st_)
                 return jnp.any(rejm | admm)
 
             def adm_body(val):
-                st_, rejt_, waves_ = val
+                st_, rejt_, waves_, rejw_ = val
                 stash, hrid, hc, hinp, hpc, need, rejm, admm = adm_masks(st_)
                 prog = rejm | admm
                 # pop the head (victim stash first — head-of-line order)
@@ -784,9 +793,21 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
                 # fold in post-loop from rejt. One flattened scatter
                 # covers every pool (request ids are disjoint across
                 # pools; non-rejecting heads aim at the scratch row).
+                # It runs under a ``cond`` only on waves where some head
+                # rejects (in any lane: the lane maximum keeps the
+                # predicate unbatched, as for the eviction pass). With
+                # the default block budget no head can reject here (a
+                # prompt that outgrows it is already rejected at submit),
+                # so the write, one row per instance head, never runs.
                 ridx = jnp.where(rejm, hc, n)
-                rejt_ = rejt_.at[ridx].set(
-                    st_["wake"], mode="promise_in_bounds"
+                rej_any = executed(jnp.any(rejm).astype(i32)) > 0
+                rejt_ = lax.cond(
+                    rej_any,
+                    lambda r: r.at[ridx].set(
+                        st_["wake"], mode="promise_in_bounds"
+                    ),
+                    lambda r: r,
+                    rejt_,
                 )
                 # admit into the first free slot (argmin over occupied —
                 # the host's np.argmin tie-break; padded slots sit past
@@ -842,11 +863,13 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
                     },
                     rejt_,
                     waves_ + 1,
+                    rejw_ + rej_any.astype(i32),
                 )
 
             with jax.named_scope("admit"):
-                st, rejt, waves = lax.while_loop(
-                    adm_cond, adm_body, (st, rejt, jnp.asarray(0, i32))
+                z = jnp.asarray(0, i32)
+                st, rejt, waves, rejw = lax.while_loop(
+                    adm_cond, adm_body, (st, rejt, z, z)
                 )
 
             with jax.named_scope("advance"):
@@ -1101,6 +1124,7 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
+                    "rej_writes": ctr["rej_writes"] + executed(rejw),
                     "rec_trips": ctr["rec_trips"] + trips,
                     "evict_runs": ctr["evict_runs"] + need.astype(i32),
                     "live_slot_rounds": ctr["live_slot_rounds"]
@@ -1319,8 +1343,8 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
         iters=int(np.max(out["iters"])),
         **{
             k: int(np.max(out[k]))
-            for k in ("rounds", "adm_waves", "rec_trips", "evict_runs",
-                      "live_peak")
+            for k in ("rounds", "adm_waves", "rej_writes", "rec_trips",
+                      "evict_runs", "live_peak")
         },
         rounds_total=int(np.sum(out["rounds"])),
         live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
@@ -1408,6 +1432,10 @@ def last_run_stats() -> dict:
     * ``rounds``: sweep rounds (≈ the pre-coalescing outer iteration
       count); a grid adds ``rounds_total``, summed over lanes;
     * ``adm_waves``: trips of the admission fixpoint;
+    * ``rej_writes``: admission waves whose gated reject write ran, that
+      is waves in which some instance head was rejected for needing more
+      blocks than its pool's whole budget (on a grid, in any lane); 0
+      under the default budget, where such a prompt is rejected at submit;
     * ``rec_trips``: record-write trips (each writes up to 32 completing
       slots' records; a round with no completion runs none);
     * ``evict_runs``: rounds in which the gate ran the eviction pass,

@@ -94,8 +94,8 @@ def grids():
     return both, alone
 
 
-COUNTERS = ("iters", "rounds", "adm_waves", "rec_trips", "evict_runs",
-            "live_slot_rounds", "slot_rows", "rounds_total")
+COUNTERS = ("iters", "rounds", "adm_waves", "rej_writes", "rec_trips",
+            "evict_runs", "live_slot_rounds", "slot_rows", "rounds_total")
 
 
 def test_counters_exist_in_both_modes(calm, grids):
@@ -192,11 +192,132 @@ def test_mixed_pressure_grid_runs_the_pass_on_need_rounds_only(mixed):
     assert calm_lane["evict_runs"] == 0
 
 
-@pytest.mark.parametrize("grid", [False, True], ids=["single", "grid"])
-def test_eviction_pass_stays_a_branch(grid):
-    """The eviction pass sits under a ``cond`` in both modes: on a grid
-    its predicate is the lane maximum, so vmap keeps the branch instead of
-    batching it into a select that runs the pass every round."""
+def test_default_budget_never_writes_rejects(calm, pressed, grids, mixed):
+    """With the default block budget (and with the pressed fleet's 90
+    blocks, over 900-token prompts) no head can need more blocks than its
+    pool holds, so no admission wave runs the gated reject write."""
+    (_, both), alone = grids
+    for stats in [calm[1], pressed[1], both, mixed[2]] + [s for _, s in alone]:
+        assert stats["rej_writes"] == 0
+        assert stats["adm_waves"] > 0
+
+
+def _reference_records(sim):
+    """{request_id: record tuple} of a reference-engine FleetSim run."""
+    return {
+        r.request_id: (r.first_token, r.finish, r.output_tokens,
+                       r.preemptions, r.truncated, r.rejected)
+        for pool in sim.pools.values() for r in pool.records
+    }
+
+
+@pytest.fixture(scope="module")
+def tight():
+    """A single lane whose block budget (40 blocks, 640 tokens) is below
+    some prompts' blocks (16-900 tokens, all under c_max): those heads
+    are rejected at admission. The jax run and the reference engine's."""
+    trace = _trace(200, 400.0, 3, (16, 900), (1, 400))
+    cfg = PoolConfig("p", 1024, 8)
+    ref = FleetSim({"p": (cfg, 3)}, DYADIC, backend="reference",
+                   coalesce_dt=0.0)
+    for inst in ref.pools["p"].instances:
+        inst.total_blocks = inst.blocks_free = 40
+    ref_res = ref.run(trace)
+    sim = FleetSim({"p": (cfg, 3)}, DYADIC, backend="jax", coalesce_dt=0.0)
+    sim.pools["p"].total_blocks = 40
+    sim.pools["p"].blocks_free[:] = 40
+    res = sim.run(trace)
+    return (ref_res, _reference_records(ref)), (
+        res, _records(sim), jax_engine.last_run_stats())
+
+
+def test_admission_rejects_match_the_reference(tight):
+    (ref_res, want), (res, got, _) = tight
+    assert res.rejections == ref_res.rejections > 0
+    rejected = {rid for rid, r in want.items() if r[-1]}
+    assert rejected == {rid for rid, r in got.items() if r[-1]}
+    assert got == want
+
+
+def test_admission_rejects_run_the_gated_write(tight):
+    """Only the waves in which some head rejects run the reject write."""
+    _, (_, _, stats) = tight
+    assert 0 < stats["rej_writes"] < stats["adm_waves"]
+
+
+#: Two pools whose short pool holds 40 blocks (640 tokens) an instance:
+#: its heads over 640 prompt tokens are rejected at admission.
+TIGHT_POOLS = {
+    "short": (PoolConfig("short", 1024, 8), 2),
+    "long": (PoolConfig("long", 8192, 8), 2),
+}
+
+
+def _tight_trace(n, rate, seed):
+    """Prompts of 16-900 tokens whose byte length follows the prompt, so
+    a low threshold keeps the long prompts off the short pool."""
+    rng = np.random.default_rng(seed)
+    arrivals = np.cumsum(rng.exponential(1.0 / rate, n))
+    out = []
+    for i in range(n):
+        inp, outp = int(rng.integers(16, 900)), int(rng.integers(1, 64))
+        out.append(Request(request_id=i, byte_len=4 * inp,
+                           max_output_tokens=outp, category=0,
+                           arrival_time=float(arrivals[i]),
+                           true_input_tokens=inp, true_output_tokens=outp))
+    return out
+
+
+@pytest.fixture(scope="module")
+def split():
+    """A two-lane grid over ``TIGHT_POOLS`` in which one lane rejects at
+    admission (threshold 1,024: every request goes short) and the other
+    does not (threshold 400: only short prompts do), and each lane alone.
+    The short pool's budget comes in through the spec rows."""
+    pool_spec = jax_engine._pool_spec
+
+    def tight_rows(name, cfg, max_inst):
+        name, c_max, n_seq, total, inst = pool_spec(name, cfg, max_inst)
+        return name, c_max, n_seq, 40 if name == "short" else total, inst
+
+    trace = _tight_trace(200, 220.0, 11)
+    runs = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax_engine, "_pool_spec", tight_rows)
+        for ths in ([[1024], [400]], [[1024]], [[400]]):
+            res = jax_engine.run_fleet_grid(trace, TIGHT_POOLS, DYADIC,
+                                            thresholds=ths,
+                                            return_records=True)
+            runs.append((res, jax_engine.last_run_stats()))
+    return runs[0], runs[1:]
+
+
+def test_rejecting_lane_leaves_the_other_lane_unchanged(split):
+    """On a wave where one lane rejects, every lane runs the write; a lane
+    with no reject writes only its scratch row, so each lane's records
+    equal its own lone run."""
+    (both, _), alone = split
+    assert list(both.rejected) == [int(r.rejected[0]) for r, _ in alone]
+    assert both.rejected[0] > 0 and both.rejected[1] == 0
+    assert both.routed[1, 0] > 0  # the calm lane still uses the short pool
+    for k, (one, _) in enumerate(alone):
+        for col, arr in both.records.items():
+            np.testing.assert_array_equal(arr[k], one.records[col][0],
+                                          err_msg=f"lane {k} {col}")
+
+
+def test_grid_runs_the_reject_write_for_any_rejecting_lane(split):
+    (_, both), alone = split
+    rejecting, calm_lane = (s for _, s in alone)
+    assert both["rej_writes"] >= rejecting["rej_writes"] > 0
+    assert calm_lane["rej_writes"] == 0
+    assert both["rej_writes"] < both["adm_waves"]
+
+
+def _cond_paths(grid):
+    """The scope path of every ``cond`` in the runner's jaxpr: the name
+    stacks of the equations that enclose it, outermost first, joined by
+    ``/`` (a sub-jaxpr's name stack is relative to its equation's)."""
     pools = (jax_engine._PoolSpec("short", 2048, 8, 2048, 2),
              jax_engine._PoolSpec("long", 8192, 8, 2048, 2))
     spec = jax_engine._SimSpec(pools=pools, w=2**-10, h=2**-13,
@@ -207,14 +328,31 @@ def test_eviction_pass_stays_a_branch(grid):
         jaxpr = jax.make_jaxpr(fn)(
             *jax_engine._abstract_inputs(spec, n, grid, g)).jaxpr
 
-    def conds(jp):
+    def conds(jp, path):
         for eqn in jp.eqns:
+            here = path + [str(eqn.source_info.name_stack)]
             if eqn.primitive.name == "cond":
-                yield str(eqn.source_info.name_stack)
+                yield "/".join(p for p in here if p)
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from conds(sub)
+                yield from conds(sub, here)
 
-    assert any(re.search(r"(^|/)evict$", name) for name in conds(jaxpr))
+    return list(conds(jaxpr, []))
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["single", "grid"])
+def test_eviction_pass_stays_a_branch(grid):
+    """The eviction pass sits under a ``cond`` in both modes: on a grid
+    its predicate is the lane maximum, so vmap keeps the branch instead of
+    batching it into a select that runs the pass every round."""
+    assert any(re.search(r"(^|/)evict$", name) for name in _cond_paths(grid))
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["single", "grid"])
+def test_reject_write_stays_a_branch(grid):
+    """The admission wave's reject write sits under a ``cond`` inside the
+    admission loop in both modes; on a grid the lane-maximum predicate
+    keeps it a branch rather than a select that writes every wave."""
+    assert any(re.search(r"(^|/)admit$", name) for name in _cond_paths(grid))
 
 
 def test_grid_counts_the_waves_the_device_executed(grids):
