@@ -119,6 +119,14 @@ class KVModelSpec:
             return 0
         return int(budget // per_seq)
 
+    def kv_blocks(self, hw: HardwareSpec) -> int:
+        """An instance's KV block budget on ``hw``: the 16-token blocks
+        that one GPU's KV budget holds (Eq. 2). Under tensor parallelism
+        every GPU holds its share of each token, so this is also the
+        whole instance's budget (``PoolConfig.kv_blocks``)."""
+        per_block = self.kv_bytes_per_token_per_gpu() * KV_BLOCK_TOKENS
+        return max(0, int(self.kv_budget_per_gpu(hw) // per_block))
+
     def n_seq_blocks(self, c_max: int, *, max_slots: int = 128) -> int:
         """Appendix-A block-budget slots (matches the paper's Table 1)."""
         blocks_per_seq = math.ceil(c_max / KV_BLOCK_TOKENS)
@@ -241,6 +249,7 @@ class CaseStudyResult:
     n_seq_short: int
     n_seq_long: int
     concurrency_ratio: float
+    kv_blocks: int  # per-instance KV block budget (PoolConfig.kv_blocks)
 
 
 def mi300x_case_study(
@@ -251,7 +260,7 @@ def mi300x_case_study(
     c_long: int = 32_768,
 ) -> CaseStudyResult:
     """Reproduce the §4.7 memory math: 23.5 KB/token/GPU, 133.4 GB KV budget,
-    676 vs 169 concurrent sequences (4×)."""
+    676 vs 169 concurrent sequences (4×), from 346,536 16-token blocks."""
     kv_kb = spec.kv_bytes_per_token_per_gpu() / 1024
     budget = spec.kv_budget_per_gpu(hw)
     n_short = spec.n_seq_memory(hw, c_short)
@@ -262,4 +271,5 @@ def mi300x_case_study(
         n_seq_short=n_short,
         n_seq_long=n_long,
         concurrency_ratio=n_short / max(1, n_long),
+        kv_blocks=spec.kv_blocks(hw),
     )

@@ -46,10 +46,24 @@ class PoolConfig:
     batch_token_budget: int = 8192  # B_batch: max batched tokens per iteration
     queue_limit: int = 256  # spillover trigger: pending requests per instance
     headroom: float = 1.05  # β queuing-headroom factor for fleet sizing
+    # KV block budget of one instance, in 16-token blocks: what its HBM
+    # holds for KV pages (``KVModelSpec.kv_blocks`` derives it for a model
+    # on an accelerator); the default is the A100 instance of App. A.
+    kv_blocks: int = TOTAL_KV_BLOCKS
 
     def admits(self, l_total: int) -> bool:
         """Hard constraint: can this pool ever serve a request of L_total?"""
         return l_total <= self.c_max
+
+    @property
+    def total_blocks(self) -> int:
+        """The KV blocks one instance of this pool serves from.
+
+        Each slot reserves ``C_max`` tokens (the paper's provisioning
+        rule), so the budget is ``min(kv_blocks, n_seq * ceil(C_max/16))``.
+        Every simulator engine reads it here."""
+        blocks_per_seq = -(-self.c_max // KV_BLOCK_TOKENS)
+        return min(self.kv_blocks, self.n_seq * blocks_per_seq)
 
 
 def short_pool(
@@ -267,5 +281,5 @@ def validate_pools(pools: Sequence[PoolConfig]) -> None:
     if len(set(names)) != len(names):
         raise ValueError(f"duplicate pool names: {names}")
     for p in pools:
-        if p.c_max <= 0 or p.n_seq <= 0:
+        if p.c_max <= 0 or p.n_seq <= 0 or p.kv_blocks <= 0:
             raise ValueError(f"pool {p.name} has non-positive capacity")

@@ -35,7 +35,6 @@ from repro.core.pools import (
     KV_BLOCK_TOKENS,
     PoolConfig,
     PoolState,
-    TOTAL_KV_BLOCKS,
 )
 from repro.core.router import Request
 from repro.obs.events import ADMIT, PREEMPT, REJECT, TRUNCATE
@@ -79,7 +78,6 @@ class InstanceSim:
         pool: PoolConfig,
         timing: TimingModel,
         *,
-        total_blocks: Optional[int] = None,
         name: str = "instance",
         pool_state: Optional[PoolState] = None,
     ) -> None:
@@ -90,14 +88,8 @@ class InstanceSim:
         # submit/admit/preempt/complete so the router reads O(1) counters
         # instead of sweeping all instances per arrival (paper §2.2).
         self.pool_state = pool_state
-        # The block budget reserves C_max tokens per slot (the paper's
-        # provisioning rule): n_seq slots x ceil(C_max/16) blocks.
-        if total_blocks is None:
-            total_blocks = min(
-                TOTAL_KV_BLOCKS, pool.n_seq * _blocks_for(pool.c_max)
-            )
-        self.total_blocks = total_blocks
-        self.blocks_free = total_blocks
+        self.total_blocks = pool.total_blocks
+        self.blocks_free = self.total_blocks
         self.queue: deque[tuple[Request, float]] = deque()
         self.active: list[_Seq] = []
         self.records: list[RequestRecord] = []

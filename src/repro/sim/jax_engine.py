@@ -164,10 +164,9 @@ from repro.core.calibration import (
     _estimate_budget_kernel,
     _update_stream_kernel,
 )
-from repro.core.pools import KV_BLOCK_TOKENS, PoolConfig, TOTAL_KV_BLOCKS
+from repro.core.pools import KV_BLOCK_TOKENS, PoolConfig
 from repro.core.router import jax_pool_ids
 from repro.kernels.sim_decode import decode_advance_jnp
-from repro.sim.engine import _blocks_for
 from repro.sim.timing import TimingModel
 from repro.traces.generator import TraceColumns
 
@@ -229,8 +228,7 @@ class _SimSpec:
 def _pool_spec(name: str, cfg: PoolConfig, max_inst: int) -> tuple:
     """A pool's spec row from its config (the block budget a fresh pool
     of that config gets)."""
-    total = min(TOTAL_KV_BLOCKS, cfg.n_seq * _blocks_for(cfg.c_max))
-    return name, cfg.c_max, cfg.n_seq, total, max_inst
+    return name, cfg.c_max, cfg.n_seq, cfg.total_blocks, max_inst
 
 
 def _sim_spec(rows, timing: TimingModel, win_size: int) -> _SimSpec:
@@ -331,8 +329,9 @@ def _init_windows(P: int, nb: int, win_cap: int) -> dict:
 def _init_counters() -> dict:
     """Loop counters carried through the rounds (see ``last_run_stats``).
 
-    ``live_slot_rounds`` is int64: live slots summed over rounds passes
-    2**31 on hour-long traces of a full fleet."""
+    ``live_slot_rounds`` and ``kv_block_rounds`` are int64: live slots
+    summed over rounds pass 2**31 on hour-long traces of a full fleet, and
+    KV blocks in use within a few hundred rounds of one."""
     z = jnp.asarray(0, jnp.int32)
     return {
         "rounds": z,
@@ -342,6 +341,7 @@ def _init_counters() -> dict:
         "evict_runs": z,
         "live_slot_rounds": jnp.asarray(0, jnp.int64),
         "live_peak": z,
+        "kv_block_rounds": jnp.asarray(0, jnp.int64),
     }
 
 
@@ -494,6 +494,14 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
     tblk_v = jnp.asarray(
         [ps.total_blocks for ps in spec.pools], jnp.int32
     )
+    # KV blocks of every real instance; padded instances hold 0 free. The
+    # loop counts blocks in use in int32 (int64 is emulated on a TPU).
+    kv_real = sum(ps.total_blocks * ps.max_inst for ps in spec.pools)
+    if kv_real >= 2**31:
+        raise ValueError(
+            f"the fleet holds {kv_real} KV blocks; the loop counts them "
+            "in int32"
+        )
     pg2 = jnp.arange(P)[:, None]
     ig2 = jnp.arange(I)[None, :]
     # Every pool's real ``(max_inst, n_seq)`` slot block, flattened in
@@ -874,6 +882,7 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
 
             with jax.named_scope("advance"):
                 nact = st["nact"]
+                free_adm = st["free"]
                 busy = due & (nact > 0)
                 idle = due & ~busy
                 wake_idle = jnp.where(
@@ -1119,8 +1128,10 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
 
                 # Loop counters: scalars beside the state update (the live
                 # slot sum is one (P, I) reduce of the post-admission nact,
-                # and the peak is a max beside it).
+                # and the peak is a max beside it; the KV blocks in use
+                # one of the post-admission free blocks).
                 live = jnp.sum(nact, dtype=i32)
+                kv_used = kv_real - jnp.sum(free_adm, dtype=i32)
                 ctr = {
                     "rounds": ctr["rounds"] + 1,
                     "adm_waves": ctr["adm_waves"] + executed(waves),
@@ -1130,6 +1141,8 @@ def _make_core(spec: _SimSpec, n: int, lanes: Optional[str] = None):
                     "live_slot_rounds": ctr["live_slot_rounds"]
                     + live.astype(jnp.int64),
                     "live_peak": jnp.maximum(ctr["live_peak"], live),
+                    "kv_block_rounds": ctr["kv_block_rounds"]
+                    + kv_used.astype(jnp.int64),
                 }
             return st, rec, rejt, ctr
 
@@ -1331,8 +1344,9 @@ def _run_span(mode: str):
 def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
     """Copy one run's loop counters into ``_LAST_RUN``.
 
-    Lane maxima are what the device executed; ``live_slot_rounds`` and
-    ``rounds_total`` sum over lanes (``last_run_stats`` has the keys)."""
+    Lane maxima are what the device executed; ``live_slot_rounds``,
+    ``kv_block_rounds`` and ``rounds_total`` sum over lanes
+    (``last_run_stats`` has the keys)."""
     P = len(spec.pools)
     I = max(ps.max_inst for ps in spec.pools)
     S = max(ps.n_seq for ps in spec.pools)
@@ -1340,6 +1354,7 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
         g=g,
         slot_rows=P * I * S,
         real_slot_rows=sum(ps.max_inst * ps.n_seq for ps in spec.pools),
+        real_kv_blocks=sum(ps.max_inst * ps.total_blocks for ps in spec.pools),
         iters=int(np.max(out["iters"])),
         **{
             k: int(np.max(out[k]))
@@ -1348,6 +1363,7 @@ def _note_counters(out: dict, spec: _SimSpec, g: int) -> None:
         },
         rounds_total=int(np.sum(out["rounds"])),
         live_slot_rounds=int(np.sum(out["live_slot_rounds"])),
+        kv_block_rounds=int(np.sum(out["kv_block_rounds"])),
     )
 
 
@@ -1423,8 +1439,10 @@ def last_run_stats() -> dict:
     ``mode`` (``"fleet"``/``"grid"``), ``n``, ``g`` (1 for a single
     lane), ``call`` (this process's run number, also on every span),
     ``slot_rows`` (P x max I x max S, the padded slot rows each round
-    works over) and ``real_slot_rows`` (the sum over pools of instances x
-    ``n_seq``, with a grid's lane maximum of instances). Loop counters, as
+    works over), ``real_slot_rows`` (the sum over pools of instances x
+    ``n_seq``, with a grid's lane maximum of instances) and
+    ``real_kv_blocks`` (the sum over pools of instances x the pool's
+    per-instance KV block budget, likewise). Loop counters, as
     the device executed them (under vmap the lanes run in lockstep until
     the last is done, so a grid reports the lane maximum unless noted):
 
@@ -1451,7 +1469,12 @@ def last_run_stats() -> dict:
       ``live_peak / real_slot_rows`` is how full the fleet got. A round
       sums instances that stand at their own clocks inside a sweep, so
       this can differ by a few requests from the most requests live at
-      one instant.
+      one instant;
+    * ``kv_block_rounds``: KV blocks in use after admission, summed over
+      the real instances, over each lane's own rounds and over lanes, so
+      ``kv_block_rounds / (rounds_total * real_kv_blocks)`` is the mean
+      share of the fleet's KV budget in use (the eviction gate opens
+      only as an instance nears its whole budget).
 
     ``spans`` holds the seconds of each host span of the run:
     ``repro.sim.run`` (the whole entry call) and its children
@@ -1697,11 +1720,8 @@ def _fleet_spec(fleet, cols: TraceColumns):
     """Build the static spec for a live FleetSim (shared with the probes)."""
     ordered = sorted(fleet._pool_index, key=fleet._pool_index.get)
     shells = [fleet.pools[name] for name in ordered]
-    # Capacities come from the live shells (not recomputed from the
-    # config) so post-construction total_blocks overrides are honored.
     rows = [
-        (name, s.config.c_max, s.config.n_seq, s.total_blocks,
-         s.num_instances)
+        _pool_spec(name, s.config, s.num_instances)
         for name, s in zip(ordered, shells)
     ]
     return _sim_spec(rows, fleet.timing, fleet._win_size), ordered, shells

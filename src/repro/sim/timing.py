@@ -12,8 +12,8 @@ Calibrations:
   reproduce Tables 1–3.
 * ``MI300X_QWEN3`` — §4.7 projection constants. The paper sizes the
   homogeneous MI300X fleet at 197 nodes for 10,000 req/s (Table 5); we
-  back-derive (W, H) from that operating point and the 4× concurrency ratio
-  (derivation in benchmarks/table5_mi300x.py).
+  back-derive (W, H) from that operating point with
+  :func:`from_operating_point` (benchmarks/table5_mi300x.py runs it).
 * ``TPU_V5E_REF`` — our TPU adaptation: W from weight HBM read per chip
   (bytes/819 GB/s over the TP group), H from per-sequence KV read at the
   pool's mean context. Used by the serving engine's performance model.
@@ -81,8 +81,49 @@ class TimingModel:
 #: Paper's calibration for Llama-3-70B on A100 (Appendix A).
 A100_LLAMA3_70B = TimingModel(name="a100-llama3-70b", w_base=8.0e-3, h_per_seq=0.65e-3)
 
-#: §4.7 projection constants (see benchmarks/table5_mi300x.py for derivation).
-MI300X_QWEN3 = TimingModel(name="mi300x-qwen3-235b", w_base=1.6e-3, h_per_seq=0.062e-3)
+#: W's share of a full iteration in the A100 calibration: 8.0 ms of
+#: 8.0 + 0.65 x 16 ms.
+A100_W_SHARE = 0.435
+
+
+def from_operating_point(
+    name: str,
+    *,
+    rate: float,
+    instances: int,
+    n_slots: int,
+    mean_iters: float,
+    w_share: float = A100_W_SHARE,
+    prefill_chunk: int = 512,
+) -> TimingModel:
+    """Back-derive (W, H) from a published fleet size.
+
+    ``instances`` instances of ``n_slots`` slots serve ``rate`` req/s at
+    full occupancy, so each does ``μ = rate / instances`` and, by
+    :meth:`TimingModel.throughput`, a full iteration takes
+    ``t = n_slots / (μ · mean_iters)``. W takes ``w_share`` of it and
+    ``H · n_slots`` the rest, the split of a calibrated platform.
+    """
+    mu = rate / instances
+    t_iter = n_slots / (mu * mean_iters)
+    return TimingModel(
+        name=name,
+        w_base=w_share * t_iter,
+        h_per_seq=(1.0 - w_share) * t_iter / n_slots,
+        prefill_chunk=prefill_chunk,
+    )
+
+
+#: §4.7 projection constants: Qwen3-235B-A22B (FP8 weights, TP 8) on
+#: MI300X, ``from_operating_point`` at Table 5's homogeneous point (197
+#: instances of 169 slots at 32,768 tokens for 10,000 req/s) with the mean
+#: iterations (279.47895, at C = 512) of 20,000 Azure requests at 10,000
+#: req/s, seed 42: the trace the fleet-azure-10k plan is sized on.
+MI300X_QWEN3 = TimingModel(
+    name="mi300x-qwen3-235b-a22b",
+    w_base=0.005181948407921241,
+    h_per_seq=3.982589744236552e-05,
+)
 
 
 def tpu_v5e_model(

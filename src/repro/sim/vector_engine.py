@@ -46,7 +46,6 @@ from repro.core.pools import (
     KV_BLOCK_TOKENS,
     PoolConfig,
     PoolState,
-    TOTAL_KV_BLOCKS,
 )
 from repro.core.router import Request
 from repro.obs.events import ADMIT, PREEMPT, REJECT, TRUNCATE
@@ -130,17 +129,12 @@ class VectorPoolSim:
         num_instances: int,
         timing: TimingModel,
         *,
-        total_blocks: Optional[int] = None,
         name: Optional[str] = None,
     ) -> None:
         self.config = config
         self.timing = timing
         self.name = name or config.name
-        if total_blocks is None:
-            total_blocks = min(
-                TOTAL_KV_BLOCKS, config.n_seq * _blocks_for(config.c_max)
-            )
-        self.total_blocks = total_blocks
+        self.total_blocks = config.total_blocks
         self.num_instances = num_instances
         self.state = PoolState(config=config, num_instances=num_instances)
 
@@ -163,7 +157,7 @@ class VectorPoolSim:
         self.preempt_carried = np.zeros((ii, ss), dtype=np.int32)
         self.seq_no = np.zeros((ii, ss), dtype=np.int64)  # admission order
         # -- per-instance state, shape (I,) ----------------------------------
-        self.blocks_free = np.full(ii, total_blocks, dtype=np.int64)
+        self.blocks_free = np.full(ii, self.total_blocks, dtype=np.int64)
         self.next_wake = np.full(ii, np.inf, dtype=np.float64)
         self.n_active = np.zeros(ii, dtype=np.int64)
         self.queue_len = np.zeros(ii, dtype=np.int64)

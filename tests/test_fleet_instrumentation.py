@@ -53,14 +53,10 @@ def _trace(n, rate, seed, l_in, l_out):
     ]
 
 
-def _single(trace, cfg, instances, total_blocks=None):
+def _single(trace, cfg, instances):
     """One single-lane jax run; (result, last_run_stats)."""
     sim = FleetSim({cfg.name: (cfg, instances)}, DYADIC, backend="jax",
                    coalesce_dt=0.0)
-    if total_blocks is not None:
-        pool = sim.pools[cfg.name]
-        pool.total_blocks = total_blocks
-        pool.blocks_free[:] = total_blocks
     res = sim.run(trace)
     return res, jax_engine.last_run_stats()
 
@@ -82,7 +78,7 @@ def calm():
 def pressed():
     """A single lane on a tiny block budget: constant preemption."""
     trace = _trace(200, 400.0, 3, (16, 900), (1, 400))
-    return _single(trace, PoolConfig("p", 1024, 8), 3, total_blocks=90)
+    return _single(trace, PoolConfig("p", 1024, 8, kv_blocks=90), 3)
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +91,8 @@ def grids():
 
 
 COUNTERS = ("iters", "rounds", "adm_waves", "rej_writes", "rec_trips",
-            "evict_runs", "live_slot_rounds", "slot_rows", "rounds_total")
+            "evict_runs", "live_slot_rounds", "slot_rows", "rounds_total",
+            "kv_block_rounds", "real_kv_blocks")
 
 
 def test_counters_exist_in_both_modes(calm, grids):
@@ -217,15 +214,11 @@ def tight():
     some prompts' blocks (16-900 tokens, all under c_max): those heads
     are rejected at admission. The jax run and the reference engine's."""
     trace = _trace(200, 400.0, 3, (16, 900), (1, 400))
-    cfg = PoolConfig("p", 1024, 8)
+    cfg = PoolConfig("p", 1024, 8, kv_blocks=40)
     ref = FleetSim({"p": (cfg, 3)}, DYADIC, backend="reference",
                    coalesce_dt=0.0)
-    for inst in ref.pools["p"].instances:
-        inst.total_blocks = inst.blocks_free = 40
     ref_res = ref.run(trace)
     sim = FleetSim({"p": (cfg, 3)}, DYADIC, backend="jax", coalesce_dt=0.0)
-    sim.pools["p"].total_blocks = 40
-    sim.pools["p"].blocks_free[:] = 40
     res = sim.run(trace)
     return (ref_res, _reference_records(ref)), (
         res, _records(sim), jax_engine.last_run_stats())
@@ -248,7 +241,7 @@ def test_admission_rejects_run_the_gated_write(tight):
 #: Two pools whose short pool holds 40 blocks (640 tokens) an instance:
 #: its heads over 640 prompt tokens are rejected at admission.
 TIGHT_POOLS = {
-    "short": (PoolConfig("short", 1024, 8), 2),
+    "short": (PoolConfig("short", 1024, 8, kv_blocks=40), 2),
     "long": (PoolConfig("long", 8192, 8), 2),
 }
 
@@ -272,23 +265,13 @@ def _tight_trace(n, rate, seed):
 def split():
     """A two-lane grid over ``TIGHT_POOLS`` in which one lane rejects at
     admission (threshold 1,024: every request goes short) and the other
-    does not (threshold 400: only short prompts do), and each lane alone.
-    The short pool's budget comes in through the spec rows."""
-    pool_spec = jax_engine._pool_spec
-
-    def tight_rows(name, cfg, max_inst):
-        name, c_max, n_seq, total, inst = pool_spec(name, cfg, max_inst)
-        return name, c_max, n_seq, 40 if name == "short" else total, inst
-
+    does not (threshold 400: only short prompts do), and each lane alone."""
     trace = _tight_trace(200, 220.0, 11)
     runs = []
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(jax_engine, "_pool_spec", tight_rows)
-        for ths in ([[1024], [400]], [[1024]], [[400]]):
-            res = jax_engine.run_fleet_grid(trace, TIGHT_POOLS, DYADIC,
-                                            thresholds=ths,
-                                            return_records=True)
-            runs.append((res, jax_engine.last_run_stats()))
+    for ths in ([[1024], [400]], [[1024]], [[400]]):
+        res = jax_engine.run_fleet_grid(trace, TIGHT_POOLS, DYADIC,
+                                        thresholds=ths, return_records=True)
+        runs.append((res, jax_engine.last_run_stats()))
     return runs[0], runs[1:]
 
 
@@ -393,6 +376,36 @@ def test_live_slot_rounds_within_the_executed_slot_rows(calm, pressed, grids,
     stats = {"calm": calm[1], "pressed": pressed[1], "grid": grids[0][1]}[which]
     rows = stats["g"] * stats["rounds"] * stats["slot_rows"]
     assert 0 < stats["live_slot_rounds"] <= rows
+
+
+def _kv_share(stats):
+    return stats["kv_block_rounds"] / (stats["rounds_total"]
+                                       * stats["real_kv_blocks"])
+
+
+@pytest.mark.parametrize("which", ["calm", "pressed", "grid"])
+def test_kv_block_rounds_within_the_budget(calm, pressed, grids, which):
+    """Blocks in use after admission, summed over rounds: at most the
+    fleet's whole budget each round."""
+    stats = {"calm": calm[1], "pressed": pressed[1], "grid": grids[0][1]}[which]
+    assert 0.0 < _kv_share(stats) <= 1.0
+
+
+def test_kv_block_rounds_follow_the_pressure(calm, pressed):
+    """The pressed fleet (90 blocks an instance, preempting all along) runs
+    near its budget; the calm one (4,096 blocks) far below it."""
+    assert pressed[1]["real_kv_blocks"] == 3 * 90
+    assert calm[1]["real_kv_blocks"] == 3 * 4096
+    assert _kv_share(pressed[1]) > 0.5 > _kv_share(calm[1])
+
+
+def test_grid_sums_kv_blocks_over_lanes(grids):
+    """Each lane adds its own rounds' blocks: two lanes together count
+    what each counts alone."""
+    (_, both), alone = grids
+    assert both["kv_block_rounds"] == sum(
+        one["kv_block_rounds"] for _, one in alone)
+    assert both["real_kv_blocks"] == alone[0][1]["real_kv_blocks"]
 
 
 @pytest.mark.parametrize("which", ["calm", "grid"])

@@ -90,8 +90,8 @@ class TestInstanceSim:
 
     def test_preemption_under_block_pressure(self):
         """Tiny block budget + growing decodes → vLLM-style preemption."""
-        pool = PoolConfig("p", 4096, 8)
-        inst = InstanceSim(pool, FAST, total_blocks=80)
+        pool = PoolConfig("p", 4096, 8, kv_blocks=80)
+        inst = InstanceSim(pool, FAST)
         for i in range(8):
             inst.submit(mk_request(i, 0.0, 64, 400), 0.0)
         t = 0.0

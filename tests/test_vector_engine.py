@@ -9,6 +9,8 @@ engines. Two-pool routed fleets relax routing to per-epoch batches, so those
 compare within tolerance.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -55,21 +57,17 @@ def poisson_trace(n, rate, seed, *, l_in=(16, 3000), l_out=(1, 400)):
 
 
 def run_single_pool(trace, config, instances, backend, *, total_blocks=None):
+    """One single-pool run; ``total_blocks`` sets each instance's KV block
+    budget through the pool's ``kv_blocks``."""
+    if total_blocks is not None:
+        config = dataclasses.replace(config, kv_blocks=total_blocks)
+        assert config.total_blocks == total_blocks
     sim = FleetSim(
         {config.name: (config, instances)},
         DYADIC,
         backend=backend,
         coalesce_dt=0.0,  # exact event ordering
     )
-    if total_blocks is not None:
-        pool = sim.pools[config.name]
-        if backend == "reference":
-            for inst in pool.instances:
-                inst.total_blocks = total_blocks
-                inst.blocks_free = total_blocks
-        else:
-            pool.total_blocks = total_blocks
-            pool.blocks_free[:] = total_blocks
     return sim, sim.run(trace)
 
 
